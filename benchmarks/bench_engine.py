@@ -38,8 +38,10 @@ bitwise-equal to the reference loop on the same seed/config.
 N-device host mesh — the flag is translated to
 ``xla_force_host_platform_device_count`` BEFORE jax initializes, which is
 why the env fixup below precedes every jax import) on a client-bound
-config; ``--mesh-sweep data=1,2,4`` spawns one subprocess per point and
-aggregates rounds/sec scaling into the report's ``mesh_scaling`` section.
+config; ``--mesh-sweep data=1,2,4`` runs one point per device count (in
+this process over sub-meshes of the chips on an accelerator, in one child
+per point on the CPU backend) and aggregates rounds/sec scaling into the
+report's ``mesh_scaling`` section.
 Each multi-device point additionally records the fused-vs-unfused
 collective ratio (the one-psum round vs the three-collective oracle) and
 the sharded-eval eval-every-round ratio; the ``--check`` gate arms on
@@ -93,6 +95,7 @@ from repro.data.federated import FederatedDataset
 from repro.data.partition import iid_partition
 from repro.fl.server import (_evaluate_eager, run_federated,
                              run_federated_reference)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import make_bundle
 
 from benchmarks.common import ART_DIR, mnist_like, print_table
@@ -266,23 +269,30 @@ def run_mesh_point(n_devices: int, r1: int = 10, r2: int = 40) -> dict:
 
 
 def run_mesh_sweep(devices, out_dir: str) -> dict:
-    """Spawn one subprocess per device count (the forced-device flag must
-    be set before jax initializes) and aggregate the scaling curve."""
+    """Run the mesh point per device count and aggregate the scaling curve.
+
+    On an accelerator the points run in THIS process, each over
+    ``make_engine_mesh(n)`` of the visible chips: a chip belongs to one
+    process, so a child could not reach it once this one holds it.  On
+    the CPU backend a point's device count is forced with an XLA flag that
+    must be set before jax initializes, so each point runs in a child with
+    the caller's environment."""
     points = []
     for n in devices:
-        path = os.path.join(out_dir, f"_mesh_{n}.json")
-        cmd = [sys.executable, "-m", "benchmarks.bench_engine",
-               "--mesh", f"data={n}", "--out", path]
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        r = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                           timeout=1800)
-        if r.returncode:
-            raise RuntimeError(f"mesh point {n} failed:\n{r.stdout}\n"
-                               f"{r.stderr}")
-        with open(path) as f:
-            points.append(json.load(f)["mesh_point"])
-        os.remove(path)
+        if jax.default_backend() != "cpu":
+            points.append(run_mesh_point(n))
+        else:
+            path = os.path.join(out_dir, f"_mesh_{n}.json")
+            cmd = [sys.executable, "-m", "benchmarks.bench_engine",
+                   "--mesh", f"data={n}", "--out", path]
+            r = subprocess.run(cmd, capture_output=True, text=True,
+                               timeout=1800)
+            if r.returncode:
+                raise RuntimeError(f"mesh point {n} failed:\n{r.stdout}\n"
+                                   f"{r.stderr}")
+            with open(path) as f:
+                points.append(json.load(f)["mesh_point"])
+            os.remove(path)
         p = points[-1]
         extra = ""
         if "collective_fused_ratio" in p:
@@ -317,21 +327,15 @@ def run_n_sweep(ns, r1: int = 50, r2: int = 450) -> dict:
     records what the dense backing would have allocated at each N (the
     page bytes / dense bytes gap is the tentpole's memory headline).
     """
-    import tempfile
-
     from repro.data.federated import TemplateClients
     from repro.data.synth import class_images
 
     # Every point compiles the SAME programs (page shapes are cohort-
     # sized, independent of N — that is the tentpole), but each engine
-    # run jits fresh function objects, so without a persistent cache
-    # every timed run would recompile ~1s of XLA whose run-to-run jitter
-    # swamps the ~ms-scale steady-state signal the flatness gate needs.
-    # Scoped to the n-sweep: the full bench path has tripped allocator
-    # crashes with the cache enabled on this jax build, and its gates
-    # are ratio-based (noise cancels) rather than flatness-based.
-    cache_dir = tempfile.mkdtemp(prefix="nsweep_xla_cache_")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # run jits fresh function objects, so every timed run would recompile
+    # ~1s of XLA whose run-to-run jitter swamps the ~ms-scale steady-state
+    # signal the flatness gate needs: cache even the quick compiles in the
+    # persistent cache main() turned on.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     K = 10
@@ -537,13 +541,14 @@ def main():
                          "cohort-paged EF store; exits non-zero unless "
                          "rounds/sec and EF page bytes stay flat in N")
     ap.add_argument("--mesh-sweep", default=None, metavar="data=1,2,4",
-                    help="run the mesh point per device count in "
-                         "subprocesses and add 'mesh_scaling' to the "
+                    help="run the mesh point per device count (children "
+                         "on the CPU backend) and add 'mesh_scaling' to the "
                          "report")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="write a jax.profiler trace of the instrumented "
                          "observability run into DIR")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mesh:
         n = int(args.mesh.split("=", 1)[1])

@@ -10,6 +10,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (fig4_fedmmd, fig5_fedfusion, fig6_newclient,
                         fig7_compression, fig8_stragglers, kernels_bench,
                         roofline_report, table2_milestones)
@@ -33,6 +35,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma-separated subset of: " + ",".join(SUITES))
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [n.strip() for n in args.only.split(",") if n.strip()] or \
         list(SUITES)

@@ -307,14 +307,9 @@ def lower_superstep(spec: SuperstepSpec, *, inner_wrap=None,
         controller=controller, input_shape=INPUT_SHAPE)
 
     if donate == "engine":
-        # the analyzer's points lower on whatever backend is present, but
-        # they model the engine's accelerator posture: staged chunk
-        # arrays donate (host_staged=True) except on CPU, exactly as
-        # engine.get_step decides at runtime
         donate = donation_argnums(
             compressed=compressed, participation=spec.participation,
-            controller=ctrl_active,
-            host_staged=jax.default_backend() != "cpu")
+            controller=ctrl_active)
     return LoweredSuperstep(
         spec=spec, fl=fl, fn=fn, args=args, donate_argnums=tuple(donate),
         cohort=cohort, ef_rows=ef_rows, uplink=uplink, downlink=downlink,

@@ -101,7 +101,8 @@ class ArchConfig:
 
     # --- performance knobs (§Perf; defaults = paper-faithful baseline) ---
     remat: str = "none"             # none | attn | layer  (activation ckpt)
-    attn_impl: str = "jnp"          # jnp | pallas (flash train kernel)
+    attn_impl: str = "jnp"          # jnp | pallas (flash train kernel) |
+    # pallas_interpret (the same kernel in the Pallas interpreter; CPU tests)
     serve_expert_parallel: bool = False  # shard experts over data at serve
     moe_shard_capacity: bool = False     # capacity dim over 'model' (no vmap)
     moe_dispatch: str = "gather"         # gather | a2a (shard_map all-to-all;
@@ -119,9 +120,9 @@ class ArchConfig:
         if self.remat not in ("none", "attn", "layer"):
             raise ValueError(f"{self.name}: remat {self.remat!r} must be "
                              "'none', 'attn' or 'layer'")
-        if self.attn_impl not in ("jnp", "pallas"):
+        if self.attn_impl not in ("jnp", "pallas", "pallas_interpret"):
             raise ValueError(f"{self.name}: attn_impl {self.attn_impl!r} "
-                             "must be 'jnp' or 'pallas'")
+                             "must be 'jnp', 'pallas' or 'pallas_interpret'")
         if self.moe_dispatch not in ("gather", "a2a"):
             raise ValueError(f"{self.name}: moe_dispatch "
                              f"{self.moe_dispatch!r} must be 'gather' or "

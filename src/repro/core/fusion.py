@@ -58,9 +58,8 @@ def fusion_aggregate(op: str, old_global, client_fusions, weights, ema_beta,
     mesh axes BEFORE the EMA (the gate statistic is a round-global
     quantity, the EMA must see the full-round average exactly once).
     """
-    from repro.core.aggregate import psum_tree
-    avg = psum_tree(jax.tree.map(
-        lambda x: jnp.tensordot(weights, x, axes=1), client_fusions), shard)
+    from repro.core.aggregate import client_weighted_sum, psum_tree
+    avg = psum_tree(client_weighted_sum(weights, client_fusions), shard)
     if op == "conv":
         return avg
     return jax.tree.map(

@@ -33,6 +33,23 @@ def _broadcast_mask(mask, labels):
         labels.shape)
 
 
+def _tree_sum(v):
+    """Sum of every element of ``v`` in one fixed pairwise order.
+
+    A ``reduce`` may be emitted with a different association wherever XLA
+    fuses it (a standalone evaluator vs. the same evaluator inside a
+    superstep's scan body), which moves a float sum by an ulp.  Halving
+    with elementwise adds pins the order, so both evaluators agree bit for
+    bit; the zero padding to a power of two adds nothing."""
+    v = v.reshape(-1)
+    n = 1 << max(v.shape[0] - 1, 0).bit_length()
+    v = jnp.pad(v, (0, n - v.shape[0]))
+    while v.shape[0] > 1:
+        h = v.shape[0] // 2
+        v = v[:h] + v[h:]
+    return v[0]
+
+
 def masked_cross_entropy_sum(logits, labels, mask):
     """Masked CE *sum* and weight sum: ``(Σ ce·w, Σ w)``.
 
@@ -45,7 +62,7 @@ def masked_cross_entropy_sum(logits, labels, mask):
     onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logits.dtype)
     gold = jnp.sum(logits * onehot, axis=-1)
     w = _broadcast_mask(mask, labels)
-    return jnp.sum((logz - gold) * w), jnp.sum(w)
+    return _tree_sum((logz - gold) * w), jnp.sum(w)
 
 
 def masked_accuracy_sum(logits, labels, mask):

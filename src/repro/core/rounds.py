@@ -83,7 +83,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FLConfig
-from repro.core.aggregate import (ClientSharding, finish_masked_loss,
+from repro.core.aggregate import (ClientSharding, client_weighted_sum,
+                                  finish_masked_loss,
                                   masked_loss_sums, mean_over_clients,
                                   normalize_weights, psum_tree,
                                   running_update, zeros_like_tree)
@@ -132,10 +133,10 @@ def _check_extra_keys(extra_keys):
 
 
 def _weighted_sums(stacked, weights):
-    """tensordot(weights, leading-client-axis tree) — the in-shard half of
-    :func:`repro.core.aggregate.weighted_mean` (psum completes it)."""
-    return jax.tree.map(
-        lambda x: jnp.tensordot(weights.astype(x.dtype), x, axes=1), stacked)
+    """Weighted client sum of a leading-client-axis tree — the in-shard
+    half of :func:`repro.core.aggregate.weighted_mean` (psum completes
+    it)."""
+    return client_weighted_sum(weights, stacked)
 
 
 def _make_plain_clients(bundle: ModelBundle, fl: FLConfig, mode: str, *,

@@ -496,7 +496,7 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
             sharding=(eval_batch_sharding(mesh) if eval_shard is not None
                       else shard_repl if shard is not None else None),
             shard=eval_shard)
-        eval_fn = make_eval_fn(bundle, fl, shard=eval_shard)
+        eval_fn = make_eval_fn(bundle, fl, shard=eval_shard, impl=impl)
         eval_in_scan = eval_every == 1 and callback is None
         if not eval_in_scan:
             jit_eval = jax.jit(make_sharded_eval(eval_fn, mesh)
@@ -604,16 +604,11 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                                           eval_fn=in_scan, impl=impl,
                                           telemetry=tele,
                                           participation=part_active)
-            # donate the carried state AND the staged chunk — batches /
-            # sizes / lrs (/cids/ridx/pmask/pstale) are consumed exactly
-            # once.  The host-staged arrays are only donatable on
-            # accelerator backends (on CPU their buffers alias host numpy
-            # memory and XLA refuses, warning on every dispatch); the lr
-            # slice is device-native and always donates.
+            # donate the carried state and the lr slice: the buffers an
+            # output can take over (see donation_argnums)
             donate = donation_argnums(
                 compressed=compressed, participation=part_active,
-                controller=ctrl_active,
-                host_staged=jax.default_backend() != "cpu")
+                controller=ctrl_active)
             steps[n_rounds] = jax.jit(fn, donate_argnums=donate)
         return steps[n_rounds]
 
@@ -687,7 +682,7 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
 
     def step_annotation(i):
         """jax.profiler chunk marker; a no-op without --profile."""
-        if profile_dir and hasattr(jax.profiler, "StepTraceAnnotation"):
+        if profile_dir:
             return jax.profiler.StepTraceAnnotation("superstep", step_num=i)
         return contextlib.nullcontext()
 

@@ -36,13 +36,15 @@ from repro.core.losses import (masked_accuracy, masked_accuracy_sum,
                                masked_cross_entropy_sum)
 
 
-def make_eval_fn(bundle, fl, shard: Optional[ClientSharding] = None):
+def make_eval_fn(bundle, fl, shard: Optional[ClientSharding] = None, *,
+                 impl: str = "auto"):
     """Traceable ``eval_metrics(global_state, batch, mask) -> {acc, loss}``.
 
     Deployment-time logits come from the algorithm plugin's
     ``deploy_logits`` hook — for FedFusion the deployed global model
     fuses its own features with itself through the aggregated fusion
     module (E_g = E_l = global), exactly as the pre-engine evaluator did.
+    ``impl`` is the kernel dispatch of that hook, as in training.
 
     With ``shard`` the function is a ``shard_map`` body over the client
     axes: ``batch``/``mask`` carry this shard's positional slice of the
@@ -55,7 +57,8 @@ def make_eval_fn(bundle, fl, shard: Optional[ClientSharding] = None):
 
     def eval_metrics(global_state, batch, mask) -> Dict:
         out = bundle.apply(global_state["model"], batch)
-        logits = algo.deploy_logits(bundle, fl, global_state, out)
+        logits = algo.deploy_logits(bundle, fl, global_state, out,
+                                    impl=impl)
         labels = bundle.labels(batch)
         if shard is None:
             return {"acc": masked_accuracy(logits, labels, mask),

@@ -55,13 +55,6 @@ from repro.launch.mesh import client_axes
 from repro.launch.sharding import (chunk_shardings,  # noqa: F401 (re-export)
                                    ef_table_sharding, eval_batch_sharding)
 
-if hasattr(jax, "shard_map"):          # jax >= 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def client_sharding(mesh) -> Optional[ClientSharding]:
     """The mesh's client-axis split, or None when it degenerates to 1."""
@@ -76,12 +69,12 @@ def client_sharding(mesh) -> Optional[ClientSharding]:
 
 
 def _unchecked_shard_map(fn, mesh, in_specs, out_specs):
-    # check_rep/check_vma off: outputs marked replicated are made identical
+    # check_vma off: outputs marked replicated are made identical
     # on every shard by construction (they are functions of replicated
     # inputs and psum results), which the static replication checker
     # cannot see through the scan carry.
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_sharded_superstep(bundle, fl, mode, n_rounds, mesh, *,

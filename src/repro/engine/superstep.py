@@ -88,34 +88,28 @@ from repro.core.rounds import (init_global_state, make_compressed_round_fn,
 from repro.kernels import ops
 
 
-def donation_argnums(*, compressed, participation=False, controller=False,
-                     host_staged=True):
+def donation_argnums(*, compressed, participation=False, controller=False):
     """The engine's ``donate_argnums`` for a superstep signature.
 
     One source of truth shared by ``repro.engine.engine`` (which jits the
     supersteps) and ``repro.analysis`` (whose donation pass verifies the
-    donated buffers are actually aliased in the compiled executable):
+    donated buffers are actually aliased in the compiled executable).
+    Only buffers an output can take over are donated, on every backend:
 
-    * carried device state always donates — ``global_state`` (and for the
-      compressed path ``ef_all`` + ``mirror``, plus the controller
-      scalars) are consumed exactly once per chunk;
-    * the staged chunk arrays (batches / sizes / cids / round_idx and
-      the participation mask/staleness) donate only when
-      ``host_staged=True`` — on CPU their buffers alias host numpy
-      memory and XLA refuses the donation;
-    * the lr slice is device-native and always donates.
+    * the carried device state — ``global_state`` (and for the compressed
+      path ``ef_all`` + ``mirror``, plus the controller scalars) — is
+      consumed exactly once per chunk and comes back with the same shapes;
+    * the lr slice ``[K]`` f32 is taken over by a ``[K]`` metric row;
+    * the staged chunk arrays (batches / sizes / cids / round_idx and the
+      participation mask/staleness) are NOT donated: no output has their
+      shapes, so XLA could never reuse them and would only warn.
     """
     if compressed:
-        donate = (0, 1, 2, 5) + (
-            ((3, 4, 6, 7) + ((9, 10) if participation else ()))
-            if host_staged else ())
+        donate = (0, 1, 2, 5)
         if controller:
             donate = donate + ((11,) if participation else (9,))
-    else:
-        donate = (0, 3) + (
-            ((1, 2) + ((4, 5) if participation else ()))
-            if host_staged else ())
-    return donate
+        return donate
+    return (0, 3)
 
 
 def abstract_superstep_args(bundle, fl, n_rounds, *, cohort, uplink=None,

@@ -104,7 +104,7 @@ class Algorithm:
         stacked extras into the round's single psum and closes them with
         :meth:`finalize_extra_sums`, so keep the two decompositions
         consistent: ``aggregate_extras(stacked, w) ==
-        finalize_extra_sums(psum(tensordot(w, stacked)))`` (true of every
+        finalize_extra_sums(psum(client_weighted_sum(w, stacked)))`` (true of every
         in-tree plugin; a plugin needing a different cross-client
         statistic should run with ``fused_collective=False``)."""
         return {}

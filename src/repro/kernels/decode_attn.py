@@ -58,7 +58,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref,
 
 
 def flash_decode(q, k_cache, v_cache, valid_len=None, *, block_l=BLOCK_L,
-                 interpret=True):
+                 interpret=False):
     """q [B,1,H,hd]; caches [B,L,KV,hd] -> [B,1,H,hd]."""
     B, _, H, hd = q.shape
     L, KV = k_cache.shape[1], k_cache.shape[2]

@@ -244,7 +244,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
 
 def make_flash_attention(*, causal=True, window: Optional[int] = None,
                          q_block=Q_BLOCK, kv_block=KV_BLOCK,
-                         interpret=True):
+                         interpret=False):
     """Returns flash(q, k, v) -> o with a flash (tile-recompute) backward.
 
     q [B,S,H,hd]; k,v [B,S,KV,hd] with H = KV*rep.  The S x S probability

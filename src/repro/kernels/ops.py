@@ -1,11 +1,9 @@
 """jit'd public wrappers for the Pallas kernels, with implementation dispatch.
 
-On CPU (this container) the default implementation is the pure-jnp oracle —
-Pallas ``interpret=True`` executes the kernel body in Python and is used by
-the correctness tests, not the hot path.  On TPU the Pallas kernels compile
-natively (``interpret=False``).
-
-Select with ``impl``: 'auto' | 'jnp' | 'pallas' | 'pallas_interpret'.
+Select with ``impl``: ``'auto'`` (the default) takes the pure-jnp oracle
+on CPU and the compiled Pallas kernel on TPU; ``'jnp'`` and ``'pallas'``
+force one; ``'pallas_interpret'`` — and only it — runs a kernel in the
+Pallas interpreter, which the CPU correctness tests use.
 """
 from __future__ import annotations
 
@@ -41,10 +39,7 @@ def mk_mmd2(x, y, widths, *, impl="auto"):
     n, m = x.shape[0], y.shape[0]
     # median-heuristic sigma from the cross sq-distances (O(nm d) but cheap
     # relative to the Gram sums; stop-grad like the oracle).
-    x2 = jnp.sum(x * x, -1)
-    y2 = jnp.sum(y * y, -1)
-    dxy = x2[:, None] + y2[None, :] - 2 * (x @ y.T)
-    sigma = jax.lax.stop_gradient(jnp.mean(dxy)) + 1e-8
+    sigma = jax.lax.stop_gradient(jnp.mean(ref.sq_dists(x, y))) + 1e-8
     sxx = gram_sum(x, x, sigma, widths, interpret=interpret)
     syy = gram_sum(y, y, sigma, widths, interpret=interpret)
     sxy = gram_sum(x, y, sigma, widths, interpret=interpret)

@@ -5,8 +5,32 @@ shapes/dtypes and assert_allclose against these).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+
+def sq_dists(a, b):
+    """Squared distances [n, m] by the ||a||^2 + ||b||^2 - 2 a.b identity.
+
+    The identity cancels, so the inner product runs at f32 precision (a
+    one-pass bf16 product, the TPU default, swamps small distances) and
+    the rounding noise below zero is clamped away."""
+    a2 = jnp.sum(a * a, axis=-1)
+    b2 = jnp.sum(b * b, axis=-1)
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    return jnp.maximum(a2[:, None] + b2[None, :] - 2.0 * ab, 0.0)
+
+
+def gram_sum_ref(x, y, sigma, widths):
+    """sum_{ij} mean_w exp(-||x_i - y_j||^2 / (2 w sigma)): the Gram sum the
+    ``gram_sum`` kernel forms tile by tile, here with the n x m matrix."""
+    d2 = sq_dists(x.astype(jnp.float32), y.astype(jnp.float32))
+    k = 0.0
+    for w in widths:
+        k = k + jnp.exp(-d2 / (2.0 * w * sigma))
+    return jnp.sum(k) / len(widths)
 
 
 def mk_mmd2_ref(x, y, widths, *, median_heuristic=True):
@@ -21,25 +45,15 @@ def mk_mmd2_ref(x, y, widths, *, median_heuristic=True):
     """
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
-
-    def sqdist(a, b):
-        a2 = jnp.sum(a * a, axis=-1)
-        b2 = jnp.sum(b * b, axis=-1)
-        return a2[:, None] + b2[None, :] - 2.0 * (a @ b.T)
-
-    dxx, dyy, dxy = sqdist(x, x), sqdist(y, y), sqdist(x, y)
     if median_heuristic:
-        sigma = jax.lax.stop_gradient(jnp.mean(dxy)) + 1e-8
+        sigma = jax.lax.stop_gradient(jnp.mean(sq_dists(x, y))) + 1e-8
     else:
         sigma = 1.0
 
-    def kmean(d2):
-        k = 0.0
-        for w in widths:
-            k = k + jnp.exp(-d2 / (2.0 * w * sigma))
-        return jnp.mean(k) / len(widths)
+    def kmean(a, b):
+        return gram_sum_ref(a, b, sigma, widths) / (a.shape[0] * b.shape[0])
 
-    return kmean(dxx) + kmean(dyy) - 2.0 * kmean(dxy)
+    return kmean(x, x) + kmean(y, y) - 2.0 * kmean(x, y)
 
 
 def fusion_conv_ref(f_g, f_l, w):
@@ -49,7 +63,13 @@ def fusion_conv_ref(f_g, f_l, w):
     axis followed by a 1x1 convolution (= matmul over channels).
     """
     C = f_g.shape[-1]
-    return f_g @ w[:C] + f_l @ w[C:]
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    return dot(f_g, w[:C]) + dot(f_l, w[C:])
+
+
+def inv_scale(scale):
+    """The f32 reciprocal of a quantizer's step, computed once per call."""
+    return jnp.float32(1.0) / jnp.asarray(scale, jnp.float32)
 
 
 def quant_pack_ref(x, scale, noise, *, bits):
@@ -60,11 +80,15 @@ def quant_pack_ref(x, scale, noise, *, bits):
     ``bits=8``: int8 codes in [-127, 127].
     ``bits=4``: codes in [-7, 7] stored as ``code+8`` nibbles, two per uint8
     (element 2i in the low nibble, 2i+1 in the high one); n must be even.
+    ``x / scale`` is taken as ``x * inv_scale(scale)``: a product rounds
+    the same in every program, while a TPU division is an expansion that
+    differs between XLA and a Pallas kernel in the last bit, enough to
+    move a code across a rounding boundary.
     """
     if bits not in (4, 8):
         raise ValueError(f"quant_pack_ref bits={bits!r} must be 4 or 8")
     qmax = 127 if bits == 8 else 7
-    q = jnp.floor(x.astype(jnp.float32) / scale + noise)
+    q = jnp.floor(x.astype(jnp.float32) * inv_scale(scale) + noise)
     q = jnp.clip(q, -qmax, qmax)
     if bits == 8:
         return q.astype(jnp.int8)

@@ -23,10 +23,14 @@ import jax           # noqa: E402
 
 from repro.configs import ARCH_CONFIGS, INPUT_SHAPES  # noqa: E402
 from repro.configs.base import ALGORITHM_NAMES, FLConfig  # noqa: E402
-from repro.launch.mesh import mesh_context, make_production_mesh    # noqa: E402
+from repro.launch.mesh import make_production_mesh    # noqa: E402
 from repro.launch.specs import skip_reason            # noqa: E402
 from repro.launch.steps import build_step             # noqa: E402
 from repro.roofline import analyze                    # noqa: E402
+
+# the chip the production meshes are made of: roofline terms divide by its
+# published peaks (repro.roofline.PEAKS), whatever device compiles here
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 ART_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "benchmarks", "artifacts", "dryrun")
@@ -61,7 +65,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     try:
         mesh = make_production_mesh(multi_pod=multi_pod)
         fn, args, in_sh, out_sh = build_step(cfg, fl, shape, mesh)
-        with mesh_context(mesh):   # sharding-constraint P specs resolve here
+        with jax.set_mesh(mesh):   # sharding-constraint P specs resolve here
             lowered = jax.jit(fn, in_shardings=in_sh,
                               out_shardings=out_sh).lower(*args)
             t_lower = time.time() - t0
@@ -71,7 +75,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         from repro.fl.api import make_algorithm  # noqa: E402 (env-var file)
         chips = mesh.size
         roof = analyze(compiled, cfg, shape, mesh_name, chips, mesh,
-                       two_stream=make_algorithm(fl.algorithm).two_stream)
+                       two_stream=make_algorithm(fl.algorithm).two_stream,
+                       device_kind=TARGET_DEVICE_KIND)
         mem = compiled.memory_analysis()
         rec.update(
             status="ok",
@@ -196,7 +201,7 @@ def run_superstep(multi_pod: bool, compressed: bool = True,
             args = (state, batches, sizes, lrs) + test_args
             in_sh = (sh_repl, sh_batch, sh_batch, sh_repl) + test_sh
 
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
         out = jax.eval_shape(fn, *args)
         rec.update(

@@ -10,47 +10,23 @@ seeing the single real CPU device).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes AxisType; 0.4.x builds Mesh without it
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    # explicit Auto axis types: silences the jax 0.9 default-change warning
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` (axis_types only where supported)."""
-    return _mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh``.
-
-    jax >= 0.5 uses ``jax.set_mesh``; on 0.4.x the ``Mesh`` object itself is
-    the context manager that sets the thread-local physical mesh.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1x1 mesh over the real local device (CPU smoke paths)."""
-    return _mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:
@@ -82,7 +58,7 @@ def make_engine_mesh(n_client_shards: int = None):
     if n > len(jax.devices()):
         raise ValueError(f"engine mesh wants {n} devices, only "
                          f"{len(jax.devices())} visible")
-    return _mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def axis_size(mesh, *names) -> int:

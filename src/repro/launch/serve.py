@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_CONFIGS
-from repro.launch.mesh import mesh_context
 from repro.launch.train import mesh_from_devices
 from repro.launch import sharding as sh
 from repro.models import transformer as tfm
@@ -41,7 +40,7 @@ def main() -> None:
     print(f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"arch={cfg.name}")
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         params_struct = jax.eval_shape(
             lambda k: tfm.init_params(cfg, k), jax.random.PRNGKey(0))
         params_sh = sh.param_shardings(mesh, params_struct, fsdp=False)

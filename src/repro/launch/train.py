@@ -25,7 +25,7 @@ from repro.fl.api import ALGORITHM_NAMES
 from repro.data.partition import source_partition
 from repro.data.synth import token_stream
 from repro.launch import sharding as sh
-from repro.launch.mesh import mesh_context
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import fl_plan
 from repro.launch.steps import build_train_step
 from repro.models.registry import make_bundle
@@ -205,6 +205,7 @@ def main() -> None:
                          "(topk fracs or quant bits) topping out at the "
                          "static codec parameter; empty -> default ladder")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ARCH_CONFIGS[args.arch]
     if args.scale == "tiny":
@@ -229,7 +230,7 @@ def main() -> None:
 
     plan = fl_plan(cfg, shape, mesh)
     bundle = make_bundle(cfg, jnp.float32)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         state = jax.jit(
             lambda k: init_global_state(bundle, fl, k),
             out_shardings=in_sh[0])(jax.random.PRNGKey(0))

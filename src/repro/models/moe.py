@@ -29,8 +29,6 @@ def _shard_capacity(x):
     parallelises the gather/scatter paths — §Perf hillclimb 3.  No-op when
     there is no mesh, no 'model' axis, or C does not divide.
     """
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        return x   # jax 0.4.x: no ambient-mesh introspection; skip the hint
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or "model" not in mesh.axis_names:
         return x

@@ -33,13 +33,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import mlp_apply
 
-if hasattr(jax, "shard_map"):          # jax >= 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 # Concrete mesh for shard_map, set by the launch layer before tracing
 # (jax.sharding.get_mesh() is unavailable inside jit; the model call stack
 # does not thread the mesh, so the launcher registers it here).
@@ -56,8 +49,7 @@ def _local_moe(xt, router, w1, w2, w3, *, top_k, act, capacity_factor,
     """Per-shard body (runs under shard_map).  xt [T_loc, d]."""
     T, d = xt.shape
     E = router.shape[1]
-    n_sh = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-            else jax.lax.psum(1, axis))  # jax 0.4.x compat
+    n_sh = jax.lax.axis_size(axis)
     E_loc = w1.shape[0]
     if E != n_sh * E_loc:
         raise ValueError(f"router has {E} experts but {n_sh} shards x "
@@ -143,12 +135,12 @@ def moe_apply_a2a(params, x, mesh=None, *, top_k, act, capacity_factor=1.25,
 
     w3 = params["w3"] if has_w3 else jnp.zeros_like(params["w1"])
     tp = "model" if "model" in mesh.axis_names else None
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes), P(), P(axis, None, tp), P(axis, tp, None),
                   P(axis, None, tp)),
         out_specs=(P(batch_axes), P()),
-        **{_CHECK_KW: False})
+        check_vma=False)
     out, aux = fn(x, params["router"], params["w1"], params["w2"], w3)
     if dense_residual:
         out = out + mlp_apply(params["dense"], x, act)

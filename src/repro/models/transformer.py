@@ -144,14 +144,13 @@ def _layer_seq(cfg: ArchConfig, kind: str, p, h, aux, *, positions,
                                    cfg.n_kv_heads, cfg.head_dim)
         q, k = _apply_rope_any(cfg, q, k, positions, mrope_pos)
         window = cfg.sliding_window if kind == ATTN_LOCAL else None
-        if cfg.attn_impl == "pallas":
+        if cfg.attn_impl in ("pallas", "pallas_interpret"):
             # Pallas flash TRAIN kernel (custom_vjp): probability tiles
             # stay in VMEM in both directions (kernels/flash_attn.py).
-            # interpret=True on CPU; compiles natively on TPU.
             from repro.kernels.flash_attn import make_flash_attention
-            interp = jax.devices()[0].platform != "tpu"
-            o = make_flash_attention(causal=True, window=window,
-                                     interpret=interp)(q, k, v)
+            o = make_flash_attention(
+                causal=True, window=window,
+                interpret=cfg.attn_impl == "pallas_interpret")(q, k, v)
         elif cfg.remat == "attn":
             # store only (q, k, v); recompute the blocked softmax in the
             # backward — otherwise the kv-block scan saves its probability

@@ -6,11 +6,13 @@ trip counts — XLA's own ``cost_analysis()`` counts each ``while`` body once,
 which under-reports scanned layers by ~n_layers; its raw numbers are kept in
 the report for transparency.
 
-Post-SPMD HLO shapes are PER-DEVICE, so analyzer outputs are per-chip:
+Post-SPMD HLO shapes are PER-DEVICE, so analyzer outputs are per-chip,
+each term over the target device's published peak (:data:`PEAKS`, keyed
+by ``jax.Device.device_kind``; a kind not in the table is an error):
 
-    compute    = flops_per_chip / 197 TFLOP/s (bf16)
-    memory     = hbm_bytes_per_chip / 819 GB/s
-    collective = collective_bytes_per_chip / 50 GB/s (ICI link)
+    compute    = flops_per_chip / peak FLOP/s (bf16)
+    memory     = hbm_bytes_per_chip / peak HBM bytes/s
+    collective = collective_bytes_per_chip / ICI bytes/s of one link
 
 MODEL_FLOPS = 6 N D (dense) / 6 N_active D (MoE); the two-stream algorithms
 add a frozen-global forward (+2 N D) which we count in MODEL_FLOPS_2STREAM
@@ -25,9 +27,34 @@ from repro.configs.base import ArchConfig, InputShape
 from repro.launch.specs import fl_plan
 from repro.roofline.hlo import analyze_entry
 
-PEAK_FLOPS = 197e12        # bf16 per chip, TPU v5e
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one device kind."""
+    flops: float        # bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # bytes/s of one inter-chip link
+    source: str
+
+
+PEAKS: Dict[str, DevicePeaks] = {
+    # 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect over
+    # the chip's four ICI links (50 GB/s each)
+    "TPU v5 lite": DevicePeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The :data:`PEAKS` entry of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") \
+            from None
 
 
 @dataclass
@@ -36,6 +63,7 @@ class Roofline:
     shape: str
     mesh: str
     chips: int
+    device_kind: str
     flops_per_chip: float
     hbm_bytes_per_chip: float
     coll_bytes_per_chip: float
@@ -48,16 +76,20 @@ class Roofline:
     peak_memory_bytes: float = 0.0
 
     @property
+    def peaks(self) -> DevicePeaks:
+        return device_peaks(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes_per_chip / HBM_BW
+        return self.hbm_bytes_per_chip / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_chip / ICI_BW
+        return self.coll_bytes_per_chip / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -76,7 +108,7 @@ class Roofline:
         t = max(self.t_compute, self.t_memory, self.t_collective)
         if t <= 0:
             return 0.0
-        return self.model_flops / (t * self.chips * PEAK_FLOPS)
+        return self.model_flops / (t * self.chips * self.peaks.flops)
 
     def to_dict(self) -> Dict:
         d = asdict(self)
@@ -105,7 +137,11 @@ def model_flops(cfg: ArchConfig, shape: InputShape, mesh,
 
 
 def analyze(compiled, cfg: ArchConfig, shape: InputShape, mesh_name: str,
-            chips: int, mesh=None, two_stream: bool = True) -> Roofline:
+            chips: int, mesh=None, two_stream: bool = True, *,
+            device_kind: str) -> Roofline:
+    """Roofline of ``compiled`` against the peaks of ``device_kind`` (the
+    chip the step is meant for, not the device it was compiled on)."""
+    device_peaks(device_kind)
     text = compiled.as_text()
     cost = analyze_entry(text)
 
@@ -124,6 +160,7 @@ def analyze(compiled, cfg: ArchConfig, shape: InputShape, mesh_name: str,
     mf = model_flops(cfg, shape, mesh, two_stream)
     return Roofline(
         arch=cfg.name, shape=shape.name, mesh=mesh_name, chips=chips,
+        device_kind=device_kind,
         flops_per_chip=cost.flops,
         hbm_bytes_per_chip=cost.bytes,
         coll_bytes_per_chip=cost.total_coll_bytes,

@@ -175,7 +175,7 @@ def test_mutation_f64_leaf_caught():
             return jax.tree.unflatten(td, leaves)
         return g
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         low = lower_superstep(SuperstepSpec(codec="topk"),
                               inner_wrap=add_f64)
         findings = make_pass("dtype").run(low)
@@ -187,12 +187,39 @@ def test_mutation_broken_donation_caught():
     from repro.engine.superstep import donation_argnums
     low = lower_superstep(SuperstepSpec(codec="topk"), donate=())
     _ = low.compiled_text       # compile WITHOUT any donation...
+    # ...then claim the engine's donations
     low.donate_argnums = donation_argnums(
-        compressed=True, participation=False, controller=False,
-        host_staged=False)      # ...then claim the engine's donations
+        compressed=True, participation=False, controller=False)
     findings = make_pass("donation").run(low)
     assert findings, "donation pass missed donation being dropped"
     assert any("aliases 0 buffer" in f.message for f in findings)
+
+
+@pytest.mark.parametrize("spec", [
+    SuperstepSpec(codec="plain", participation=True),
+    SuperstepSpec(codec="topk", participation=True, controller="ef_ratio"),
+], ids=lambda s: s.point)
+def test_donation_argnums_all_usable(spec):
+    """The engine donates the same argnums on every backend, and each of
+    them is taken over by an output: the widest signatures compile here
+    with every donated leaf aliased and no "not usable" warning, which is
+    what the chip compile must show too."""
+    low = lower_superstep(spec)
+    findings = make_pass("donation").run(low)
+    assert not findings, [str(f) for f in findings]
+    assert not [w for w in low.compile_warnings if "donat" in w.lower()]
+
+
+def test_mutation_unusable_staged_donation_caught():
+    """Donating the staged chunk (batches / sizes / cids / round_idx), as
+    the engine once did off-CPU, leaves buffers no output can take: XLA
+    warns and the donation pass reports it."""
+    from repro.engine.superstep import donation_argnums
+    donate = donation_argnums(compressed=True) + (3, 4, 6, 7)
+    low = lower_superstep(SuperstepSpec(codec="topk"), donate=donate)
+    findings = make_pass("donation").run(low)
+    assert any("donat" in f.message.lower() for f in findings), \
+        [str(f) for f in findings]
 
 
 def test_mutation_fake_wire_model_caught():
@@ -246,8 +273,7 @@ _SHARDED_ANALYSIS_SCRIPT = textwrap.dedent("""
     low3 = lower_superstep(spec, donate=())
     _ = low3.compiled_text
     low3.donate_argnums = donation_argnums(
-        compressed=True, participation=False, controller=False,
-        host_staged=False)
+        compressed=True, participation=False, controller=False)
     fs = make_pass("donation").run(low3)
     assert any("aliases 0 buffer" in f.message for f in fs), \\
         [str(f) for f in fs]

@@ -6,7 +6,8 @@ import pytest
 
 from repro.configs import CNN_CONFIGS
 from repro.configs.base import FLConfig
-from repro.core.aggregate import normalize_weights, weighted_mean
+from repro.core.aggregate import (client_weighted_sum, normalize_weights,
+                                  weighted_mean)
 from repro.core.fusion import fusion_aggregate, fusion_apply, fusion_init
 from repro.core.local import make_local_loss, make_local_trainer
 from repro.core.rounds import init_global_state, make_round_fn
@@ -209,6 +210,26 @@ def test_weighted_mean_respects_n_t():
     w = normalize_weights(jnp.array([300.0, 100.0]))
     out = weighted_mean(stacked, w)
     np.testing.assert_allclose(out["w"], 1.5)  # 0.75*1 + 0.25*3
+
+
+
+def test_client_weighted_sum_adds_clients_in_order():
+    """Clients are added one after another from zero.  In f32, 1e8 + 1
+    rounds back to 1e8, so the association shows in the result: client
+    order gives (1e8 - 1e8) + 1 = 1 and (1 + 1e8) - 1e8 = 0, where a
+    reordered reduction would give another answer in one column."""
+    x = {"w": jnp.array([[1e8, 1.0], [-1e8, 1e8], [1.0, -1e8]], jnp.float32)}
+    got = jax.jit(client_weighted_sum)(jnp.ones((3,), jnp.float32), x)
+    np.testing.assert_array_equal(got["w"], np.array([1.0, 0.0], np.float32))
+    k = jax.random.split(jax.random.PRNGKey(3), 2)
+    stacked = {"w": jax.random.normal(k[0], (10, 5, 5, 8)),
+               "b": jax.random.normal(k[1], (10, 7))}
+    w = normalize_weights(jnp.arange(1.0, 11.0))
+    got = jax.jit(client_weighted_sum)(w, stacked)
+    for key in stacked:
+        np.testing.assert_allclose(
+            got[key], jnp.tensordot(w, stacked[key], axes=1), rtol=1e-5,
+            atol=1e-6)
 
 
 def test_identical_clients_fixed_point():

@@ -120,6 +120,22 @@ def test_engine_eval_every_round_in_scan():
     assert all("acc" in h for h in eng.comm.history)
 
 
+@pytest.mark.parametrize("impl,n_kernels", [("jnp", 0),
+                                             ("pallas_interpret", 1)])
+def test_eval_fn_dispatches_kernels_by_impl(impl, n_kernels):
+    """The evaluator runs the deploy-time fusion operator with the engine's
+    ``impl``, as training does: ``impl="jnp"`` traces no Pallas kernel."""
+    from repro.core.rounds import init_global_state
+    from repro.engine import make_eval_fn, pad_eval_batch
+    bundle = _bundle()
+    fl = FLConfig(algorithm="fedfusion", fusion_op="conv")
+    state = init_global_state(bundle, fl, jax.random.PRNGKey(0))
+    batch, mask = pad_eval_batch(_data().test_batch())
+    jaxpr = jax.make_jaxpr(make_eval_fn(bundle, fl, impl=impl))(
+        state, batch, mask)
+    assert str(jaxpr).count("pallas_call") == n_kernels
+
+
 @pytest.mark.parametrize("codec", ["identity", "topk"])
 def test_engine_checkpoint_resume_matches_reference(tmp_path, codec):
     """Interrupt at round 4, resume to 8 — both loops land on the same

@@ -21,16 +21,6 @@ from repro.kernels.mk_mmd import gram_sum
 WIDTHS = (1.0, 2.0, 4.0, 8.0, 16.0)
 
 
-def _gram_sum_ref(x, y, sigma, widths):
-    x = x.astype(jnp.float32)
-    y = y.astype(jnp.float32)
-    d2 = (jnp.sum(x * x, -1)[:, None] + jnp.sum(y * y, -1)[None, :]
-          - 2.0 * x @ y.T)
-    d2 = jnp.maximum(d2, 0.0)
-    acc = sum(jnp.exp(-d2 / (2.0 * w * sigma)) for w in widths)
-    return jnp.sum(acc) / len(widths)
-
-
 @pytest.mark.parametrize("n,m,d", [
     (8, 8, 4), (16, 8, 32), (100, 64, 16),      # non-aligned n
     (130, 130, 8),                               # > 1 tile (tile=128)
@@ -43,9 +33,22 @@ def test_gram_sum_matches_ref(n, m, d, dtype):
     y = jax.random.normal(ky, (m, d), dtype)
     sigma = 3.7
     got = gram_sum(x, y, sigma, WIDTHS, interpret=True)
-    want = _gram_sum_ref(x, y, sigma, WIDTHS)
+    want = ref.gram_sum_ref(x, y, sigma, WIDTHS)
     np.testing.assert_allclose(got, want, rtol=2e-2 if dtype == jnp.bfloat16
                                else 1e-5)
+
+
+@pytest.mark.parametrize("n,m,d", [(16, 16, 8), (64, 32, 32), (130, 70, 16)])
+def test_mk_mmd2_grad_pallas_matches_jnp(n, m, d):
+    """Reverse mode through the kernel (its custom VJP) == autodiff of the
+    oracle, for both feature batches."""
+    kx, ky = jax.random.split(jax.random.PRNGKey(1))
+    x = jax.random.normal(kx, (n, d))
+    y = 0.5 * jax.random.normal(ky, (m, d)) + 1.0
+    grad = lambda impl: jax.grad(  # noqa: E731
+        lambda a, b: ops.mk_mmd2(a, b, WIDTHS, impl=impl), (0, 1))(x, y)
+    for got, want in zip(grad("pallas_interpret"), grad("jnp")):
+        np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-4)
 
 
 @pytest.mark.parametrize("n,m,d", [(16, 16, 8), (64, 32, 32), (130, 70, 16)])
@@ -131,6 +134,23 @@ def test_fusion_conv_matches_ref(shape, C, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("shape,C", [((4, 16), 16), ((2, 5, 5, 64), 64),
+                                     ((300, 128), 128)])
+def test_fusion_conv_grad_matches_ref(shape, C):
+    """Reverse mode through the kernel (its custom VJP) == autodiff of the
+    oracle, for both feature streams and the operator weight."""
+    ks = jax.random.split(jax.random.PRNGKey(C), 3)
+    fg = jax.random.normal(ks[0], shape)
+    fl = jax.random.normal(ks[1], shape)
+    w = jax.random.normal(ks[2], (2 * C, C)) / np.sqrt(2 * C)
+    loss = lambda impl: lambda a, b, c: jnp.sum(  # noqa: E731
+        ops.fused_fusion_conv(a, b, c, impl=impl) ** 2)
+    got = jax.grad(loss("pallas_interpret"), (0, 1, 2))(fg, fl, w)
+    want = jax.grad(loss("jnp"), (0, 1, 2))(fg, fl, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)
 
 
 def test_fusion_conv_equals_concat_matmul():
@@ -235,8 +255,13 @@ def test_gqa_flash_decode_wrapper_paths_agree():
 # EF gather / scatter (repro.engine's device-resident error-feedback table)
 # ---------------------------------------------------------------------------
 
+# the last case is longer than one VMEM slab of a row (MAX_EF_ROWS x 128):
+# the row is cut into slabs and the grid runs over (client, slab)
+_LONG_ROW = 1030 * 128 + 3
+
+
 @pytest.mark.parametrize("N,n,k", [(6, 256, 3), (5, 100, 5), (16, 384, 4),
-                                   (3, 7, 2)])
+                                   (3, 7, 2), (4, _LONG_ROW, 2)])
 def test_ef_gather_matches_ref(N, n, k):
     key = jax.random.PRNGKey(N * n)
     table = jax.random.normal(key, (N, n))
@@ -246,7 +271,8 @@ def test_ef_gather_matches_ref(N, n, k):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("N,n,k", [(6, 256, 3), (5, 100, 5), (16, 384, 4)])
+@pytest.mark.parametrize("N,n,k", [(6, 256, 3), (5, 100, 5), (16, 384, 4),
+                                   (4, _LONG_ROW, 2)])
 def test_ef_scatter_matches_ref(N, n, k):
     ks = jax.random.split(jax.random.PRNGKey(N + n), 3)
     table = jax.random.normal(ks[0], (N, n))

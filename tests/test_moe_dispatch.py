@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch.mesh import make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_mod
 from repro.models.moe_dispatch import moe_apply_a2a, set_dispatch_mesh
 
@@ -27,7 +27,7 @@ def test_a2a_matches_reference_single_shard():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 4, d))
     want = moe_mod.moe_reference(params, x, top_k=k, act="silu")
     set_dispatch_mesh(mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         got, aux = moe_apply_a2a(params, x, top_k=k, act="silu",
                                  capacity_factor=float(E))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -44,14 +44,14 @@ def test_a2a_matches_reference_multi_shard():
         from repro.models import moe as moe_mod
         from repro.models.moe_dispatch import moe_apply_a2a, set_dispatch_mesh
 
-        from repro.launch.mesh import make_mesh, mesh_context
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("data",))
         d, f, E, k = 16, 32, 8, 2
         params = moe_mod.moe_init(jax.random.PRNGKey(0), d, E, f, "silu")
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 4, d))
         want = moe_mod.moe_reference(params, x, top_k=k, act="silu")
         set_dispatch_mesh(mesh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             got, _ = jax.jit(lambda p, xx: moe_apply_a2a(
                 p, xx, top_k=k, act="silu", capacity_factor=float(E)))(
                     params, x)
@@ -77,14 +77,14 @@ def test_a2a_ep_tp_matches_reference():
         from repro.models import moe as moe_mod
         from repro.models.moe_dispatch import moe_apply_a2a, set_dispatch_mesh
 
-        from repro.launch.mesh import make_mesh, mesh_context
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 2), ("data", "model"))
         d, f, E, k = 16, 32, 4, 2
         params = moe_mod.moe_init(jax.random.PRNGKey(0), d, E, f, "silu")
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 4, d))
         want = moe_mod.moe_reference(params, x, top_k=k, act="silu")
         set_dispatch_mesh(mesh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             got, _ = jax.jit(lambda p, xx: moe_apply_a2a(
                 p, xx, top_k=k, act="silu", capacity_factor=float(E)))(
                     params, x)
@@ -110,7 +110,7 @@ def test_a2a_tight_capacity_drops_like_gather_path():
     x = jnp.broadcast_to(
         jax.random.normal(jax.random.PRNGKey(1), (1, 1, d)), (1, 16, d))
     set_dispatch_mesh(mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         tight, _ = moe_apply_a2a(params, x, top_k=k, act="silu",
                                  capacity_factor=0.25)
         full, _ = moe_apply_a2a(params, x, top_k=k, act="silu",

@@ -54,10 +54,11 @@ def test_serve_ep_flag_accepted_on_host_mesh():
 
 
 def test_pallas_attn_impl_matches_jnp_end_to_end():
-    """attn_impl='pallas' (flash train kernel, interpret mode on CPU) gives
-    the same loss and gradients as the jnp scan path inside a full model."""
+    """attn_impl='pallas_interpret' (the flash train kernel in the Pallas
+    interpreter) gives the same loss and gradients as the jnp scan path
+    inside a full model."""
     base = ARCH_CONFIGS["smollm-135m"].reduced()
-    cfg_p = dataclasses.replace(base, attn_impl="pallas")
+    cfg_p = dataclasses.replace(base, attn_impl="pallas_interpret")
     params = tfm.init_params(base, jax.random.PRNGKey(0))
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                           base.vocab_size)}

@@ -97,3 +97,14 @@ ENTRY %main (p0: f32[128,256]) -> f32[128,256] {
     cost = analyze_entry(text)
     assert cost.coll_bytes["all-reduce"] == 128 * 256 * 4
     assert cost.coll_counts["all-reduce"] == 1
+
+
+def test_device_peaks_keyed_by_kind():
+    """Roofline terms divide by the peaks of the device kind they are for;
+    a kind with no published peaks is an error, never a default."""
+    from repro.roofline import PEAKS, device_peaks
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert v5e.source and set(PEAKS) == {"TPU v5 lite"}
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("cpu")
