@@ -40,16 +40,37 @@ def _as_jaxpr(jaxpr):
     return getattr(jaxpr, "jaxpr", jaxpr)
 
 
+def eqn_subjaxprs(eqn) -> Iterator:
+    """The sub-jaxprs one equation carries (scan / while bodies, cond
+    branches, pjit and custom-derivative closures)."""
+    is_sub = lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")
+    for v in eqn.params.values():
+        for j in jax.tree_util.tree_leaves(v, is_leaf=is_sub):
+            inner = (j.jaxpr if hasattr(j, "jaxpr")
+                     else (j if hasattr(j, "eqns") else None))
+            if inner is not None:
+                yield inner
+
+
 def subjaxprs(jaxpr) -> Iterator:
     """Immediate sub-jaxprs referenced by ``jaxpr``'s equations."""
-    is_sub = lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")
     for eqn in _as_jaxpr(jaxpr).eqns:
-        for v in eqn.params.values():
-            for j in jax.tree_util.tree_leaves(v, is_leaf=is_sub):
-                inner = (j.jaxpr if hasattr(j, "jaxpr")
-                         else (j if hasattr(j, "eqns") else None))
-                if inner is not None:
-                    yield inner
+        yield from eqn_subjaxprs(eqn)
+
+
+def leaf_eqns_with_name_stacks(jaxpr, prefix: str = "") -> Iterator:
+    """``(name_stack, eqn)`` for every equation that carries no
+    sub-jaxpr, recursively, with its full name stack: the enclosing
+    equations' stacks joined to its own, as lowering composes them into
+    a device op's ``tf_op`` (``vmap(local_train)/transpose(jvp())``)."""
+    for eqn in _as_jaxpr(jaxpr).eqns:
+        own = str(eqn.source_info.name_stack)
+        path = f"{prefix}/{own}" if prefix and own else (prefix or own)
+        subs = list(eqn_subjaxprs(eqn))
+        if not subs:
+            yield path, eqn
+        for sub in subs:
+            yield from leaf_eqns_with_name_stacks(sub, path)
 
 
 def iter_eqns(jaxpr) -> Iterator:

@@ -23,10 +23,16 @@ expectations they pin are the engine's structural contracts:
   bytes of the optimized HLO (``repro.roofline.hlo``) equal the
   jaxpr-level execution model exactly, and the codec wire model charged
   by the CommLog stays consistent (compressed < ideal f32 bytes, ladder
-  monotone with its top rung at the static wire bytes).
+  monotone with its top rung at the static wire bytes);
+* ``scopes`` — every equation of the round body, backward included,
+  lies under exactly one of the round's four device scopes
+  (``repro.obs.scopes``), apart from vmap's broadcast of an unbatched
+  output; the codecs' sort / top-k lies under ``codec`` and every
+  convolution under ``local_train`` or ``eval``.
 """
 from __future__ import annotations
 
+import re
 from collections import Counter
 from typing import List
 
@@ -37,8 +43,9 @@ from repro.analysis.jaxprs import (COLLECTIVE_PRIMITIVES,
                                    collect_avals,
                                    collective_execution_model,
                                    count_collectives, find_primitives,
-                                   round_body)
+                                   leaf_eqns_with_name_stacks, round_body)
 from repro.analysis.registry import AnalysisPass, Finding, register_pass
+from repro.obs.scopes import PARTS
 
 # jax dtype -> HLO shape-prefix, for matching donated leaves against the
 # compiled module's entry parameters
@@ -275,3 +282,72 @@ class CollectiveBytesPass(AnalysisPass):
                 f"{nbytes} B) — XLA inserted or dropped collective "
                 f"traffic the bytes model does not account for"))
         return out
+
+
+# a part's name as one segment of a name stack, whatever transforms wrap
+# it: "codec/...", ".../vmap(local_train)/...", "transpose(jvp(eval))"
+_PART_RE = re.compile(r"(?:^|[/(])(" + "|".join(PARTS) + r")(?=$|[/)])")
+
+# the one equation JAX itself adds under no part: vmap moving an
+# unbatched output (a telemetry tap's round-wide level) onto the client
+# axis as it leaves the vmapped client step
+_VMAP_EXIT = re.compile(r"^vmap\(\)(/vmap\(\))*$")
+
+
+def _plumbing(stack, eqn) -> bool:
+    return (eqn.primitive.name == "broadcast_in_dim"
+            and _VMAP_EXIT.match(stack) is not None)
+
+
+# primitives that must sit under given parts
+_ANCHORS = {"sort": ("codec",), "top_k": ("codec",),
+            "conv_general_dilated": ("local_train", "eval")}
+
+
+def parts_of(name_stack: str) -> frozenset:
+    """The round parts a name stack (or a device op's ``tf_op``) lies
+    under."""
+    return frozenset(_PART_RE.findall(name_stack))
+
+
+def scope_findings(body) -> List[str]:
+    """What breaks the scope contract in a round body (or any traced
+    round-part jaxpr): equations under no part, under two, or in the
+    wrong part."""
+    bare, multi, misplaced = Counter(), Counter(), Counter()
+    for stack, eqn in leaf_eqns_with_name_stacks(body):
+        prim = eqn.primitive.name
+        found = parts_of(stack)
+        if not found and not _plumbing(stack, eqn):
+            bare[prim] += 1
+        elif len(found) > 1:
+            multi[stack] += 1
+        want = _ANCHORS.get(prim)
+        if want and not found & set(want):
+            misplaced[f"{prim} under {stack!r}"] += 1
+    out = []
+    if bare:
+        out.append(f"round-body equations under none of {PARTS}: "
+                   f"{dict(bare)}")
+    if multi:
+        out.append(f"equations under two parts at once: {dict(multi)}")
+    if misplaced:
+        out.append(f"equations in the wrong part: {dict(misplaced)}")
+    return out
+
+
+@register_pass
+class ScopesPass(AnalysisPass):
+    name = "scopes"
+    scope = "lowered"
+    description = ("every round-body equation lies under exactly one of "
+                   "local_train / codec / aggregate / eval (vmap's "
+                   "broadcast of an unbatched output excepted); sorts "
+                   "under codec, convs under local_train or eval")
+
+    def run(self, low) -> List[Finding]:
+        body = round_body(low.jaxpr)
+        if body is None:
+            return [self.finding(low.point,
+                                 "no round scan found in the superstep")]
+        return [self.finding(low.point, m) for m in scope_findings(body)]

@@ -17,6 +17,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import AGGREGATE, in_part
+
 
 @dataclass(frozen=True)
 class ClientSharding:
@@ -52,6 +54,7 @@ class ClientSharding:
         return pos
 
 
+@in_part(AGGREGATE)
 def psum_tree(tree, shard: ClientSharding):
     """``psum`` every leaf over the client axes (identity when unsharded)."""
     if shard is None:
@@ -97,6 +100,7 @@ def fused_psum(tree, shard: ClientSharding):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+@in_part(AGGREGATE)
 def normalize_weights(n_examples, shard: ClientSharding = None):
     n = jnp.asarray(n_examples, jnp.float32)
     total = jnp.sum(n)
@@ -105,6 +109,15 @@ def normalize_weights(n_examples, shard: ClientSharding = None):
     return n / total
 
 
+@in_part(AGGREGATE)
+def weights_over_total(n_examples, total):
+    """The round's example weights against a psum-completed ``total``
+    (the fused rounds pipeline it one collective ahead): the same
+    quotient as :func:`normalize_weights`, bit for bit."""
+    return jnp.asarray(n_examples, jnp.float32) / total
+
+
+@in_part(AGGREGATE)
 def client_weighted_sum(weights, stacked_tree):
     """``sum_c weights[c] * x[c]`` over the leading client axis of every
     leaf, added in client order from zero by a loop over the clients.
@@ -133,6 +146,7 @@ def client_weighted_sum(weights, stacked_tree):
     return jax.lax.fori_loop(0, leaves[0].shape[0], add, zeros)
 
 
+@in_part(AGGREGATE)
 def weighted_mean(stacked_tree, weights, shard: ClientSharding = None):
     """stacked_tree: pytree with leading client axis; weights [n_clients].
 
@@ -143,6 +157,7 @@ def weighted_mean(stacked_tree, weights, shard: ClientSharding = None):
     return psum_tree(client_weighted_sum(weights, stacked_tree), shard)
 
 
+@in_part(AGGREGATE)
 def mean_over_clients(values, shard: ClientSharding = None):
     """Mean of a per-client [C_local] array over the FULL round's clients."""
     m = jnp.mean(values)
@@ -151,6 +166,7 @@ def mean_over_clients(values, shard: ClientSharding = None):
     return jax.lax.pmean(m, shard.axis_name)
 
 
+@in_part(AGGREGATE)
 def masked_loss_sums(losses, pmask):
     """Psum-pending numerator/denominator of a participation-masked mean
     loss.  Rides whatever collective the caller already makes (the fused
@@ -160,6 +176,7 @@ def masked_loss_sums(losses, pmask):
     return {"lsum": jnp.sum(losses * m), "lw": jnp.sum(m)}
 
 
+@in_part(AGGREGATE)
 def finish_masked_loss(summed):
     """Post-psum completion of :func:`masked_loss_sums` (the staleness /
     participation finish step: division happens once, after the sum over
@@ -167,11 +184,13 @@ def finish_masked_loss(summed):
     return summed["lsum"] / jnp.maximum(summed["lw"], 1.0)
 
 
+@in_part(AGGREGATE)
 def running_update(acc_tree, tree, weight):
     """acc += weight * tree   (client_sequential accumulation)."""
     return jax.tree.map(lambda a, x: a + weight.astype(x.dtype) * x,
                         acc_tree, tree)
 
 
+@in_part(AGGREGATE)
 def zeros_like_tree(tree):
     return jax.tree.map(jnp.zeros_like, tree)

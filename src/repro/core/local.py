@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import FLConfig
 from repro.models.registry import ModelBundle
+from repro.obs.scopes import LOCAL_TRAIN, in_part
 from repro.optim import make_optimizer
 
 
@@ -64,6 +65,7 @@ def make_local_trainer(bundle: ModelBundle, fl: FLConfig, *, impl="auto"):
     cache = (fl.cache_global_features and algo.two_stream
              and fl.local_epochs > 1)
 
+    @in_part(LOCAL_TRAIN)
     def local_train(global_model, global_extra, batches, lr):
         trainable: Dict[str, Any] = algo.init_trainable(fl, global_model,
                                                         global_extra)

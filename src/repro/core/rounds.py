@@ -87,9 +87,11 @@ from repro.core.aggregate import (ClientSharding, client_weighted_sum,
                                   finish_masked_loss,
                                   masked_loss_sums, mean_over_clients,
                                   normalize_weights, psum_tree,
-                                  running_update, zeros_like_tree)
+                                  running_update, weights_over_total,
+                                  zeros_like_tree)
 from repro.core.local import _algorithm, make_local_trainer
 from repro.models.registry import ModelBundle
+from repro.obs.scopes import AGGREGATE, CODEC, in_part, part
 # repro.obs sits at the bottom of the import graph (jax only) — no cycle
 from repro.obs.telemetry import ClientTapCtx
 
@@ -113,6 +115,7 @@ _RESERVED_CONTRIB_KEYS = frozenset(("model", "delta", "loss", "lsum", "lw",
                                     "tele"))
 
 
+@in_part(AGGREGATE)
 def _sum_clients(tele):
     """[C]-stacked per-client tap sums -> this shard's scalar sums (the
     psum-pending half of the round's telemetry; {} passes through)."""
@@ -279,6 +282,7 @@ def make_round_fn(bundle: ModelBundle, fl: FLConfig, mode: str, *,
     run_clients = _make_plain_clients(bundle, fl, mode, impl=impl,
                                       telemetry=telemetry)
 
+    @in_part(AGGREGATE)
     def _finish(global_state, summed, stacked_extras, weights):
         if mode == "client_parallel":
             new_state: Dict[str, Any] = {"model": summed["model"]}
@@ -372,7 +376,7 @@ def make_round_parts(bundle: ModelBundle, fl: FLConfig, mode: str, *,
     if participation:
         def local_fn(global_state, client_batches, total, n_examples, lr,
                      pmask, pstale):
-            weights = jnp.asarray(n_examples, jnp.float32) / total
+            weights = weights_over_total(n_examples, total)
             wsums, _, losses, tele = run_clients(
                 global_state, client_batches, weights, lr, n_examples,
                 pmask, pstale)
@@ -380,11 +384,12 @@ def make_round_parts(bundle: ModelBundle, fl: FLConfig, mode: str, *,
                     "tele": tele}
     else:
         def local_fn(global_state, client_batches, total, n_examples, lr):
-            weights = jnp.asarray(n_examples, jnp.float32) / total
+            weights = weights_over_total(n_examples, total)
             wsums, _, losses, tele = run_clients(
                 global_state, client_batches, weights, lr, n_examples)
-            return {**wsums, "loss": jnp.mean(losses), "tele": tele}
+            return {**wsums, "loss": mean_over_clients(losses), "tele": tele}
 
+    @in_part(AGGREGATE)
     def finish_fn(global_state, summed):
         new_state: Dict[str, Any] = {"model": summed["model"]}
         new_state.update(algo.finalize_extra_sums(
@@ -464,6 +469,7 @@ def make_compressed_round_fn(bundle: ModelBundle, fl: FLConfig, mode: str,
                                            telemetry=telemetry,
                                            controller=controller)
 
+    @in_part(AGGREGATE)
     def _finish(global_state, summed, stacked_extras, weights):
         # apply the aggregate update to the FULL-PRECISION server model;
         # the aggregate of the client models themselves is bcast+Σw·Δ, but
@@ -502,7 +508,8 @@ def make_compressed_round_fn(bundle: ModelBundle, fl: FLConfig, mode: str,
                                     weights)
                 metrics = {"local_loss": finish_masked_loss(summed)}
                 metrics.update(telemetry.finish(summed["tele"]))
-                new_ctrl = controller.update(ctrl_state, metrics)
+                with part(AGGREGATE):
+                    new_ctrl = controller.update(ctrl_state, metrics)
                 return new_state, metrics, new_ef, bcast, new_ctrl
         else:
             def round_fn(global_state, client_batches, n_examples, lr,
@@ -521,7 +528,8 @@ def make_compressed_round_fn(bundle: ModelBundle, fl: FLConfig, mode: str,
                                     weights)
                 metrics = {"local_loss": mean_over_clients(losses, shard)}
                 metrics.update(telemetry.finish(summed["tele"]))
-                new_ctrl = controller.update(ctrl_state, metrics)
+                with part(AGGREGATE):
+                    new_ctrl = controller.update(ctrl_state, metrics)
                 return new_state, metrics, new_ef, bcast, new_ctrl
 
         return round_fn
@@ -610,31 +618,33 @@ def _make_compressed_clients(bundle: ModelBundle, fl: FLConfig, mode: str,
                     down_mirror, key, n_examples=None, pmask=None,
                     pstale=None, level=None):
         n_clients = weights.shape[0]
-        kd, ku = jax.random.split(key)
-        down_update = jax.tree.map(lambda m, w: m - w,
-                                   global_state["model"], down_mirror)
-        down_payload, _ = downlink.encode(
-            down_update, downlink.init_state(),   # stateless: see above
-            kd if downlink.uses_key else None)
-        bcast = jax.tree.map(lambda w, d: w + d.astype(w.dtype),
-                             down_mirror, downlink.decode(down_payload))
+        with part(CODEC):
+            kd, ku = jax.random.split(key)
+            down_update = jax.tree.map(lambda m, w: m - w,
+                                       global_state["model"], down_mirror)
+            down_payload, _ = downlink.encode(
+                down_update, downlink.init_state(),   # stateless: see above
+                kd if downlink.uses_key else None)
+            bcast = jax.tree.map(lambda w, d: w + d.astype(w.dtype),
+                                 down_mirror, downlink.decode(down_payload))
+            client_keys = _local_client_keys(ku, n_clients, shard)
+            eff_bytes = (None if level is None or controller is None
+                         else jnp.take(controller.spec.bytes_table(), level))
         gx = algo.extra_from_state(global_state)
-        client_keys = _local_client_keys(ku, n_clients, shard)
-        eff_bytes = (None if level is None or controller is None
-                     else jnp.take(controller.spec.bytes_table(), level))
 
         def client_step(batches, ef, ck, nex=None, m=None, st=None):
             trainable, loss = trainer(bcast, gx, batches, lr)
-            delta = jax.tree.map(lambda a, b: a - b, trainable["model"],
-                                 bcast)
-            payload, new_ef = uplink.encode(
-                delta, ef, ck if uplink.uses_key else None, level=level)
-            decoded = uplink.decode(payload)
-            if m is not None:
-                # dropped / late client: its payload never uplinked, so
-                # the residual it would have cleared stays local intact
-                new_ef = jax.tree.map(
-                    lambda n, o: jnp.where(m > 0, n, o), new_ef, ef)
+            with part(CODEC):
+                delta = jax.tree.map(lambda a, b: a - b, trainable["model"],
+                                     bcast)
+                payload, new_ef = uplink.encode(
+                    delta, ef, ck if uplink.uses_key else None, level=level)
+                decoded = uplink.decode(payload)
+                if m is not None:
+                    # dropped / late client: its payload never uplinked,
+                    # so the residual it would have cleared stays local
+                    new_ef = jax.tree.map(
+                        lambda n, o: jnp.where(m > 0, n, o), new_ef, ef)
             out = {"delta": decoded, "ef": new_ef, "loss": loss}
             for k in extra_keys:
                 out[k] = trainable[k]
@@ -775,7 +785,7 @@ def make_compressed_round_parts(bundle: ModelBundle, fl: FLConfig,
             def local_fn(global_state, client_batches, total, n_examples,
                          lr, ef_state, down_mirror, key, pmask, pstale,
                          ctrl_state):
-                weights = jnp.asarray(n_examples, jnp.float32) / total
+                weights = weights_over_total(n_examples, total)
                 wsums, _, new_ef, losses, bcast, tele = run_clients(
                     global_state, client_batches, weights, lr, ef_state,
                     down_mirror, key, n_examples, pmask, pstale,
@@ -786,15 +796,16 @@ def make_compressed_round_parts(bundle: ModelBundle, fl: FLConfig,
         else:
             def local_fn(global_state, client_batches, total, n_examples,
                          lr, ef_state, down_mirror, key, ctrl_state):
-                weights = jnp.asarray(n_examples, jnp.float32) / total
+                weights = weights_over_total(n_examples, total)
                 wsums, _, new_ef, losses, bcast, tele = run_clients(
                     global_state, client_batches, weights, lr, ef_state,
                     down_mirror, key, n_examples,
                     level=ctrl_state["level"])
-                contribs = {**wsums, "loss": jnp.mean(losses),
+                contribs = {**wsums, "loss": mean_over_clients(losses),
                             "tele": tele}
                 return contribs, {"new_ef": new_ef, "bcast": bcast}
 
+        @in_part(AGGREGATE)
         def finish_fn(global_state, summed, ctrl_state):
             new_model = jax.tree.map(lambda g, d: g + d.astype(g.dtype),
                                      global_state["model"], summed["delta"])
@@ -814,7 +825,7 @@ def make_compressed_round_parts(bundle: ModelBundle, fl: FLConfig,
     if participation:
         def local_fn(global_state, client_batches, total, n_examples, lr,
                      ef_state, down_mirror, key, pmask, pstale):
-            weights = jnp.asarray(n_examples, jnp.float32) / total
+            weights = weights_over_total(n_examples, total)
             wsums, _, new_ef, losses, bcast, tele = run_clients(
                 global_state, client_batches, weights, lr, ef_state,
                 down_mirror, key, n_examples, pmask, pstale)
@@ -824,13 +835,15 @@ def make_compressed_round_parts(bundle: ModelBundle, fl: FLConfig,
     else:
         def local_fn(global_state, client_batches, total, n_examples, lr,
                      ef_state, down_mirror, key):
-            weights = jnp.asarray(n_examples, jnp.float32) / total
+            weights = weights_over_total(n_examples, total)
             wsums, _, new_ef, losses, bcast, tele = run_clients(
                 global_state, client_batches, weights, lr, ef_state,
                 down_mirror, key, n_examples)
-            contribs = {**wsums, "loss": jnp.mean(losses), "tele": tele}
+            contribs = {**wsums, "loss": mean_over_clients(losses),
+                        "tele": tele}
             return contribs, {"new_ef": new_ef, "bcast": bcast}
 
+    @in_part(AGGREGATE)
     def finish_fn(global_state, summed):
         new_model = jax.tree.map(lambda g, d: g + d.astype(g.dtype),
                                  global_state["model"], summed["delta"])

@@ -65,6 +65,7 @@ from __future__ import annotations
 
 import contextlib
 import copy
+import functools
 import os
 import time
 from dataclasses import dataclass, field
@@ -191,6 +192,32 @@ def _auto_chunk_rounds(get_step, build_calib, run_step, *,
     return int(np.clip(round(overhead / (per_round * target)), lo, hi))
 
 
+def _fit_prologue(engine):
+    """Run ``engine`` inside the fit's ``fit.prologue`` span.
+
+    The span opens at the engine's entry, with the clock that
+    ``stats["fit_start_s"]`` reads; the engine closes it as its loop takes
+    the first chunk (``prologue.close()``), and an error before then
+    closes it here.  A runlog given as a path is opened, streamed and
+    closed here."""
+    @functools.wraps(engine)
+    def run(bundle, fl, data, *, rounds, runlog=None, **options):
+        t0 = time.perf_counter()
+        owns_runlog = runlog is not None and not hasattr(runlog, "span")
+        rl = as_runlog(runlog)
+        try:
+            with contextlib.ExitStack() as prologue:
+                prologue.enter_context(rl.span("fit.prologue",
+                                               rounds=rounds))
+                return engine(bundle, fl, data, rounds=rounds, runlog=rl,
+                              prologue=(prologue, t0), **options)
+        finally:
+            if owns_runlog:
+                rl.close()
+    return run
+
+
+@_fit_prologue
 def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                          rounds: int, seed: int = 0,
                          mode: str = "client_parallel",
@@ -207,7 +234,8 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                          ef_store: str = "auto",
                          telemetry=False, runlog=None,
                          halt_on_nonfinite: bool = False,
-                         profile_dir: Optional[str] = None) -> ServerResult:
+                         profile_dir: Optional[str] = None,
+                         prologue=None) -> ServerResult:
     """Engine-backed server loop (see module docstring).
 
     Drop-in for the reference loop: same arguments, same ServerResult,
@@ -237,11 +265,20 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
       extra host syncs, and the trained model stays bitwise-equal to a
       telemetry-off run;
     * ``runlog`` — None | JSONL path | :class:`repro.obs.runlog.RunLog`:
-      host span tracing (chunk dispatch, eval dispatch, prefetch staging,
-      checkpoint saves) plus counters and non-finite-metric warnings; a
-      path given here is opened, streamed and closed by the engine;
+      host span tracing (``fit.prologue`` with its ``ef.init``,
+      ``chunk.dispatch``, ``eval.dispatch``, ``prefetch.stage`` with its
+      ``.sample`` / ``.put`` / ``.fence`` parts, ``ef.page.*``,
+      ``checkpoint.save``) plus counters and non-finite-metric warnings;
+      a path given here is opened, streamed and closed by the engine.
+      Whatever the sink, every span is also a host event of any
+      ``jax.profiler`` session that is recording, and every chunk a
+      ``superstep`` step (``StepTraceAnnotation``); the device ops carry
+      the round's scopes (``repro.obs.scopes``);
     * ``profile_dir`` — start a ``jax.profiler`` trace into the directory
-      for the whole run, with one ``StepTraceAnnotation`` per chunk.
+      for the whole run, compile included.
+
+    ``prologue`` is the ``fit.prologue`` span and its start, opened by
+    the wrapper (:func:`_fit_prologue`); callers never pass it.
 
     Robustness (both off by default — the defaults keep every traced code
     path byte-identical to the pre-robustness engine):
@@ -268,6 +305,8 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
     from repro.fl.comm import CommLog
     from repro.fl.participation import make_policy
 
+    prologue, t_fit = prologue
+    rl = runlog
     shard = client_sharding(mesh) if mesh is not None else None
     n_sampled = min(fl.clients_per_round, data.n_clients)
 
@@ -321,12 +360,6 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                                 global_state)
     lr_at = exp_decay_per_round(fl.lr, fl.lr_decay)
     comm = CommLog().bind_sizes(global_state)
-
-    # host span tracing opens early: the EF pager threads its staging /
-    # write-back spans through the same sink.  A path here means the
-    # engine owns the sink's lifetime (stream + close).
-    owns_runlog = runlog is not None and not hasattr(runlog, "span")
-    rl = as_runlog(runlog)
 
     # --- wire codecs: EF store (dense device table | cohort-paged) + mirror
     compressed = fl.compressed
@@ -382,40 +415,41 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                          and os.path.exists(ef_path))
         if shard is not None:
             ef_sh = ef_table_sharding(mesh)
-        if ef_paged:
-            pager = EFPager(store, mesh=mesh, impl=impl, runlog=rl)
-            if resume_ef:
-                # ef.npz is always the compact [n_clients, ...] layout;
-                # the store keeps only the non-zero rows of it
-                ef_dense = jax.tree.map(
+        with rl.span("ef.init", paged=ef_paged, resume=resume_ef):
+            if ef_paged:
+                pager = EFPager(store, mesh=mesh, impl=impl, runlog=rl)
+                if resume_ef:
+                    # ef.npz is always the compact [n_clients, ...] layout;
+                    # the store keeps only the non-zero rows of it
+                    ef_dense = jax.tree.map(
+                        lambda z: np.zeros((data.n_clients,) + z.shape,
+                                           np.dtype(z.dtype)), ef_template)
+                    ef_dense, down_host = load_tree(
+                        ef_path, (ef_dense, global_state["model"]))
+                    store.from_dense(ef_dense)
+                    down_mirror = jax.tree.map(
+                        lambda z: _stage(jnp.asarray(z)), down_host)
+                else:
+                    down_mirror = _device_copy(global_state["model"])
+            else:
+                ef_all = jax.tree.map(
                     lambda z: np.zeros((data.n_clients,) + z.shape,
                                        np.dtype(z.dtype)), ef_template)
-                ef_dense, down_host = load_tree(
-                    ef_path, (ef_dense, global_state["model"]))
-                store.from_dense(ef_dense)
-                down_mirror = jax.tree.map(
-                    lambda z: _stage(jnp.asarray(z)), down_host)
-            else:
-                down_mirror = _device_copy(global_state["model"])
-        else:
-            ef_all = jax.tree.map(
-                lambda z: np.zeros((data.n_clients,) + z.shape,
-                                   np.dtype(z.dtype)), ef_template)
-            if resume_ef:
-                # ef.npz is always the compact [n_clients, ...] layout
-                ef_all, down_host = load_tree(
-                    ef_path, (ef_all, global_state["model"]))
-                down_mirror = jax.tree.map(
-                    lambda z: _stage(jnp.asarray(z)), down_host)
-            else:
-                down_mirror = _device_copy(global_state["model"])
-            if shard is not None:
-                # resident scratch-row layout: one permanent write-sink
-                # row per shard block, so the per-round scatter is in place
-                ef_all = insert_scratch_rows(ef_all, shard.n_shards)
-            ef_all = jax.tree.map(
-                lambda z: (jax.device_put(z, ef_sh) if shard is not None
-                           else jnp.asarray(z)), ef_all)
+                if resume_ef:
+                    # ef.npz is always the compact [n_clients, ...] layout
+                    ef_all, down_host = load_tree(
+                        ef_path, (ef_all, global_state["model"]))
+                    down_mirror = jax.tree.map(
+                        lambda z: _stage(jnp.asarray(z)), down_host)
+                else:
+                    down_mirror = _device_copy(global_state["model"])
+                if shard is not None:
+                    # resident scratch-row layout: one permanent write-sink
+                    # row per shard block, so the per-round scatter is in place
+                    ef_all = insert_scratch_rows(ef_all, shard.n_shards)
+                ef_all = jax.tree.map(
+                    lambda z: (jax.device_put(z, ef_sh) if shard is not None
+                               else jnp.asarray(z)), ef_all)
         round_key = jax.random.fold_in(key, 0x636f6d70)  # "comp"
 
     # --- observability: telemetry taps + host span tracing ----------------
@@ -513,34 +547,38 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
     # (the "device" IS the host), so reuse would corrupt staged chunks —
     # CPU stages into fresh arrays, exactly the pre-pool behaviour.
     pool = StagingPool() if jax.default_backend() != "cpu" else None
+    # seconds the fit's chunks spent in each part of their staging (the
+    # children of prefetch.stage; calibration chunks are not counted)
+    stage_s = {"sample": 0.0, "put": 0.0, "fence": 0.0}
+
+    @contextlib.contextmanager
+    def staging(part_name, r0, r1, counted):
+        t0 = time.perf_counter()
+        with rl.span("prefetch.stage." + part_name, r0=r0, r1=r1):
+            yield
+        if counted:
+            stage_s[part_name] += time.perf_counter() - t0
 
     def build_chunk(r0, r1, src=None, staging_pool=None):
-        out = (src or data).round_chunk(
-            r1 - r0, c_round, fl.local_steps, fl.local_batch,
-            pool=staging_pool, participation=select_fn)
-        if select_fn is not None:
-            cids, batches, sizes, part = out
-            # the whole participation outcome is weight-borne: dropped /
-            # late clients are zeroed (mask), staleness-discounted
-            # (weight) and truncation-scaled (work) HERE, on the host, so
-            # the staged example weights drive the unchanged normalized
-            # weighted mean — the fused one-psum never learns masking
-            # exists.  pmask/pstale only reach the round fns for EF
-            # preservation, the masked loss lanes and telemetry.
-            sizes = sizes * part["mask"] * part["weight"] * part["work"]
-        else:
-            cids, batches, sizes, part = out + (None,)
-        staged = {
-            "batches": {k: _stage(v, sharded_like=True)
-                        for k, v in batches.items()},
-            "sizes": _stage(sizes, sharded_like=True),
-            # one vectorized schedule op, not K scalar dispatches — the
-            # elementwise pow gives the same float32 values as the
-            # reference loop's per-round lr_at(r)
-            "lrs": lr_at(jnp.arange(r0, r1)),
-        }
-        if compressed:   # only the compressed superstep consumes these
-            if ef_paged:
+        counted = src is None
+        with staging("sample", r0, r1, counted):
+            out = (src or data).round_chunk(
+                r1 - r0, c_round, fl.local_steps, fl.local_batch,
+                pool=staging_pool, participation=select_fn)
+            if select_fn is not None:
+                cids, batches, sizes, part = out
+                # the whole participation outcome is weight-borne:
+                # dropped / late clients are zeroed (mask), staleness-
+                # discounted (weight) and truncation-scaled (work) HERE,
+                # on the host, so the staged example weights drive the
+                # unchanged normalized weighted mean — the fused one-psum
+                # never learns masking exists.  pmask/pstale only reach
+                # the round fns for EF preservation, the masked loss
+                # lanes and telemetry.
+                sizes = sizes * part["mask"] * part["weight"] * part["work"]
+            else:
+                cids, batches, sizes, part = out + (None,)
+            if compressed and ef_paged:
                 # the superstep addresses EF rows by VIRTUAL cid — a slot
                 # in the chunk's [K*C, ...] page.  Real training chunks
                 # gather the page from the store (ordered after the
@@ -553,32 +591,47 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                         cids, shard.n_shards if shard is not None else 1)
                     page = jax.tree_util.tree_unflatten(
                         store._treedef, pager.zero_page(plan))
-                staged["cids"] = _stage(plan.vcids)
-                staged["ef_page"] = jax.tree.map(
-                    lambda z: (jax.device_put(z, ef_sh)
-                               if shard is not None else jnp.asarray(z)),
-                    page)
-                staged["ef_plan"] = plan
-            else:
-                staged["cids"] = _stage(cids)
-            staged["ridx"] = _stage(np.arange(r0, r1, dtype=np.int32))
-        if part is not None:
-            staged["pmask"] = _stage(part["mask"], sharded_like=True)
-            staged["pstale"] = _stage(part["staleness"], sharded_like=True)
-            # host-only accounting: simulated round wall-clock and the
-            # partial uplink count ride the MetricsPump alongside the
-            # device fetch — no device round-trip involved
-            staged["host"] = {
-                "metrics": {"sim_time": part["round_time"],
-                            "arrived": part["n_arrived"].astype(np.float32)},
-                "n_up": part["n_arrived"],
+        with staging("put", r0, r1, counted):
+            staged = {
+                "batches": {k: _stage(v, sharded_like=True)
+                            for k, v in batches.items()},
+                "sizes": _stage(sizes, sharded_like=True),
+                # one vectorized schedule op, not K scalar dispatches —
+                # the elementwise pow gives the same float32 values as
+                # the reference loop's per-round lr_at(r)
+                "lrs": lr_at(jnp.arange(r0, r1)),
             }
+            if compressed:   # only the compressed superstep consumes these
+                if ef_paged:
+                    staged["cids"] = _stage(plan.vcids)
+                    staged["ef_page"] = jax.tree.map(
+                        lambda z: (jax.device_put(z, ef_sh)
+                                   if shard is not None else jnp.asarray(z)),
+                        page)
+                    staged["ef_plan"] = plan
+                else:
+                    staged["cids"] = _stage(cids)
+                staged["ridx"] = _stage(np.arange(r0, r1, dtype=np.int32))
+            if part is not None:
+                staged["pmask"] = _stage(part["mask"], sharded_like=True)
+                staged["pstale"] = _stage(part["staleness"],
+                                          sharded_like=True)
+                # host-only accounting: simulated round wall-clock and the
+                # partial uplink count ride the MetricsPump alongside the
+                # device fetch — no device round-trip involved
+                staged["host"] = {
+                    "metrics": {"sim_time": part["round_time"],
+                                "arrived": part["n_arrived"].astype(
+                                    np.float32)},
+                    "n_up": part["n_arrived"],
+                }
         if staging_pool is not None:
             # free the pool's host buffers for the next chunk: the wait
             # lands on the PREFETCH thread, never the dispatch thread
             # (ef_plan is host metadata, not an array)
-            jax.block_until_ready(
-                {k: v for k, v in staged.items() if k != "ef_plan"})
+            with staging("fence", r0, r1, counted):
+                jax.block_until_ready(
+                    {k: v for k, v in staged.items() if k != "ef_plan"})
         return staged
 
     # --- jitted supersteps, cached per chunk length -----------------------
@@ -680,12 +733,6 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                        verbose=verbose, runlog=rl,
                        schedule=ctrl_schedule)
 
-    def step_annotation(i):
-        """jax.profiler chunk marker; a no-op without --profile."""
-        if profile_dir:
-            return jax.profiler.StepTraceAnnotation("superstep", step_num=i)
-        return contextlib.nullcontext()
-
     rl.event("run.start", rounds=rounds, start_round=start_round,
              chunk_rounds=chunk_rounds, compressed=compressed,
              client_shards=shard.n_shards if shard is not None else 1,
@@ -697,13 +744,17 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
     if profile_dir:
         jax.profiler.start_trace(profile_dir)
     halted_at = None
+    fit_start_s = None
     try:
         # the pump context drains into the CommLog on a clean exit and
         # ABORTS (cancel + non-blocking shutdown) when unwinding an
         # exception — a mid-run error no longer leaks the worker thread
         with pump:
             for ci, (r0, r1, staged) in enumerate(prefetcher):
-                with step_annotation(ci):
+                if ci == 0:
+                    prologue.close()
+                with jax.profiler.StepTraceAnnotation("superstep",
+                                                      step_num=ci):
                     with rl.span("chunk.dispatch", r0=r0, r1=r1,
                                  compile=(r1 - r0) not in steps):
                         step = get_step(r1 - r0)
@@ -735,6 +786,8 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
                         else:
                             global_state, mstack = run_step(step, staged,
                                                             global_state)
+                    if fit_start_s is None:
+                        fit_start_s = time.perf_counter() - t_fit
                     eval_metrics = None
                     if jit_eval is not None and eval_every \
                             and r1 % eval_every == 0:
@@ -801,9 +854,12 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
         "eval_overlap": snap is not None,
         "host_wait_s": round(prefetcher.wait_s, 4),
         "metrics_wait_s": round(pump.wait_s, 4),
+        "stage_sample_s": round(stage_s["sample"], 4),
+        "stage_put_s": round(stage_s["put"], 4),
+        "stage_fence_s": round(stage_s["fence"], 4),
+        "fit_start_s": (round(fit_start_s, 4) if fit_start_s is not None
+                        else None),
         "telemetry": tele is not None,
-        "staging_pool_hits": pool.hits if pool is not None else 0,
-        "staging_pool_misses": pool.misses if pool is not None else 0,
         "participation": policy.name if part_active else None,
         "round_cohort": c_round,
         "halted_at": halted_at,
@@ -825,12 +881,7 @@ def run_federated_engine(bundle: ModelBundle, fl: FLConfig, data, *,
         rl.counter("ef.page.stall_s", stats["ef_stall_s"])
     rl.counter("prefetch.wait_s", stats["host_wait_s"])
     rl.counter("metrics.wait_s", stats["metrics_wait_s"])
-    if pool is not None:
-        rl.counter("staging.pool_hits", pool.hits)
-        rl.counter("staging.pool_misses", pool.misses)
     rl.event("run.end", rounds=rounds)
-    if owns_runlog:
-        rl.close()
     if rl.path:
         stats["runlog"] = rl.path
     return ServerResult(global_state=global_state, comm=comm, stats=stats)

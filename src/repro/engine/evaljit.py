@@ -34,6 +34,7 @@ from repro.core.aggregate import ClientSharding, fused_psum
 from repro.core.losses import (masked_accuracy, masked_accuracy_sum,
                                masked_cross_entropy,
                                masked_cross_entropy_sum)
+from repro.obs.scopes import EVAL, in_part
 
 
 def make_eval_fn(bundle, fl, shard: Optional[ClientSharding] = None, *,
@@ -55,6 +56,7 @@ def make_eval_fn(bundle, fl, shard: Optional[ClientSharding] = None, *,
     from repro.fl.api import make_algorithm   # lazy: fl sits above engine
     algo = make_algorithm(fl.algorithm)
 
+    @in_part(EVAL)
     def eval_metrics(global_state, batch, mask) -> Dict:
         out = bundle.apply(global_state["model"], batch)
         logits = algo.deploy_logits(bundle, fl, global_state, out,

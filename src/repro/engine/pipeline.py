@@ -59,8 +59,6 @@ class StagingPool:
 
     def __init__(self):
         self._bufs: Dict[str, np.ndarray] = {}
-        self.hits = 0       # takes served from an existing buffer
-        self.misses = 0     # takes that had to allocate
 
     def take(self, name: str, shape, dtype) -> np.ndarray:
         buf = self._bufs.get(name)
@@ -68,9 +66,6 @@ class StagingPool:
                 or buf.dtype != np.dtype(dtype):
             buf = np.empty(shape, dtype)
             self._bufs[name] = buf
-            self.misses += 1
-        else:
-            self.hits += 1
         return buf
 
 

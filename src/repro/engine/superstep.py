@@ -86,6 +86,7 @@ from repro.core.rounds import (init_global_state, make_compressed_round_fn,
                                make_compressed_round_parts, make_round_fn,
                                make_round_parts)
 from repro.kernels import ops
+from repro.obs.scopes import AGGREGATE, CODEC, in_part, part
 
 
 def donation_argnums(*, compressed, participation=False, controller=False):
@@ -266,8 +267,9 @@ def _make_fused_plain_superstep(bundle, fl, mode, n_rounds, *, eval_fn,
             contribs = local_fn(state, b, total, n, lr, pm, ps)
         else:
             contribs = local_fn(state, b, total, n, lr)
-        summed = fused_psum({"round": contribs,
-                             "total": _size_total(n_next)}, shard)
+        with part(AGGREGATE):
+            summed = fused_psum({"round": contribs,
+                                 "total": _size_total(n_next)}, shard)
         state, metrics = finish_fn(state, summed["round"])
         if eval_fn is not None:
             metrics = {**metrics, **eval_fn(state, test[0], test[1])}
@@ -276,8 +278,9 @@ def _make_fused_plain_superstep(bundle, fl, mode, n_rounds, *, eval_fn,
     if participation:
         def superstep(global_state, batches, sizes, lrs, pmask, pstale,
                       *test):
-            total = fused_psum({"total": _size_total(sizes[0])},
-                               shard)["total"]
+            with part(AGGREGATE):
+                total = fused_psum({"total": _size_total(sizes[0])},
+                                   shard)["total"]
             if n_rounds == 1:
                 b0 = jax.tree.map(lambda a: a[0], batches)
                 state, _, m = one_round(global_state, total, b0, sizes[0],
@@ -302,8 +305,9 @@ def _make_fused_plain_superstep(bundle, fl, mode, n_rounds, *, eval_fn,
 
     def superstep(global_state, batches, sizes, lrs, *test):
         # prologue: round 0's weight total (later rounds' ride the scan)
-        total = fused_psum({"total": _size_total(sizes[0])},
-                           shard)["total"]
+        with part(AGGREGATE):
+            total = fused_psum({"total": _size_total(sizes[0])},
+                               shard)["total"]
         if n_rounds == 1:
             b0 = jax.tree.map(lambda a: a[0], batches)
             state, _, m = one_round(global_state, total, b0, sizes[0],
@@ -499,6 +503,7 @@ def make_compressed_superstep(bundle, fl, mode, n_rounds, uplink, downlink,
                                         participation=participation,
                                         controller=controller)
 
+    @in_part(CODEC)
     def gather_rows(ef_all, cids, c_loc):
         if shard is None:
             return jax.tree.map(
@@ -509,6 +514,7 @@ def make_compressed_superstep(bundle, fl, mode, n_rounds, uplink, downlink,
                 ef_all),
             shard, c_loc)
 
+    @in_part(CODEC)
     def scatter_rows(ef_all, cids, new_ef):
         if shard is None:
             return jax.tree.map(
@@ -522,7 +528,8 @@ def make_compressed_superstep(bundle, fl, mode, n_rounds, uplink, downlink,
     def one_round(state, ef_all, mirror, b, n, lr, cids, r, round_key, test,
                   pm=None, ps=None):
         ef_round = gather_rows(ef_all, cids, n.shape[0])
-        key_r = jax.random.fold_in(round_key, r)
+        with part(CODEC):   # the round key feeds the codecs alone
+            key_r = jax.random.fold_in(round_key, r)
         if participation:
             state, metrics, new_ef, mirror = round_fn(
                 state, b, n, lr, ef_round, mirror, key_r, pm, ps)
@@ -538,7 +545,8 @@ def make_compressed_superstep(bundle, fl, mode, n_rounds, uplink, downlink,
         def one_round_ctrl(state, ef_all, mirror, ctrl, b, n, lr, cids, r,
                            round_key, test, pm=None, ps=None):
             ef_round = gather_rows(ef_all, cids, n.shape[0])
-            key_r = jax.random.fold_in(round_key, r)
+            with part(CODEC):   # the round key feeds the codecs alone
+                key_r = jax.random.fold_in(round_key, r)
             if participation:
                 state, metrics, new_ef, mirror, ctrl = round_fn(
                     state, b, n, lr, ef_round, mirror, key_r, pm, ps, ctrl)
@@ -689,32 +697,45 @@ def _make_fused_compressed_superstep(bundle, fl, mode, n_rounds, uplink,
         telemetry=telemetry, participation=participation,
         controller=controller)
 
+    def round_psum(contribs, ef_all, new_ef, cid, cid_next, n_next):
+        """The round's one psum: its contribution sums, the EF rows'
+        scatter placement, round r+1's gather terms and weight total."""
+        with part(CODEC):
+            scat = jax.tree.map(
+                lambda rows: _ef_place_positional(rows, shard), new_ef)
+            gath = jax.tree.map(
+                lambda t, rows: _ef_gather_next_contrib(
+                    t, cid, cid_next, rows, shard, impl=impl),
+                ef_all, new_ef)
+        with part(AGGREGATE):
+            return fused_psum({"round": contribs, "scat": scat,
+                               "gath": gath, "total": _size_total(n_next)},
+                              shard)
+
+    @in_part(CODEC)
+    def land_rows(ef_all, summed, cid, c_loc):
+        """Scatter the owned rows of the psum into the resident block;
+        slice round r+1's positional EF rows out of it."""
+        ef_all = jax.tree.map(
+            lambda t, full: _ef_scatter_local(t, cid, full, shard,
+                                              impl=impl),
+            ef_all, summed["scat"])
+        return ef_all, _slice_positional(summed["gath"], shard, c_loc)
+
     def one_round(state, ef_all, mirror, ef_rows, total, b, n, lr, cid,
                   cid_next, n_next, r, round_key, test, pm=None, ps=None):
-        key_r = jax.random.fold_in(round_key, r)
+        with part(CODEC):   # the round key feeds the codecs alone
+            key_r = jax.random.fold_in(round_key, r)
         if participation:
             contribs, aux = local_fn(state, b, total, n, lr, ef_rows,
                                      mirror, key_r, pm, ps)
         else:
             contribs, aux = local_fn(state, b, total, n, lr, ef_rows,
                                      mirror, key_r)
-        summed = fused_psum({
-            "round": contribs,
-            "scat": jax.tree.map(
-                lambda rows: _ef_place_positional(rows, shard),
-                aux["new_ef"]),
-            "gath": jax.tree.map(
-                lambda t, rows: _ef_gather_next_contrib(
-                    t, cid, cid_next, rows, shard, impl=impl),
-                ef_all, aux["new_ef"]),
-            "total": _size_total(n_next),
-        }, shard)
+        summed = round_psum(contribs, ef_all, aux["new_ef"], cid, cid_next,
+                            n_next)
         state, metrics = finish_fn(state, summed["round"])
-        ef_all = jax.tree.map(
-            lambda t, full: _ef_scatter_local(t, cid, full, shard,
-                                              impl=impl),
-            ef_all, summed["scat"])
-        ef_next = _slice_positional(summed["gath"], shard, n.shape[0])
+        ef_all, ef_next = land_rows(ef_all, summed, cid, n.shape[0])
         if eval_fn is not None:
             metrics = {**metrics, **eval_fn(state, test[0], test[1])}
         return state, ef_all, aux["bcast"], ef_next, summed["total"], metrics
@@ -726,30 +747,18 @@ def _make_fused_compressed_superstep(bundle, fl, mode, n_rounds, uplink,
         # round's summed tap metrics AFTER the single collective and its
         # state transition is pure replicated scalar math, so adaptivity
         # adds zero collectives to the round.
-        key_r = jax.random.fold_in(round_key, r)
+        with part(CODEC):   # the round key feeds the codecs alone
+            key_r = jax.random.fold_in(round_key, r)
         if participation:
             contribs, aux = local_fn(state, b, total, n, lr, ef_rows,
                                      mirror, key_r, pm, ps, ctrl)
         else:
             contribs, aux = local_fn(state, b, total, n, lr, ef_rows,
                                      mirror, key_r, ctrl)
-        summed = fused_psum({
-            "round": contribs,
-            "scat": jax.tree.map(
-                lambda rows: _ef_place_positional(rows, shard),
-                aux["new_ef"]),
-            "gath": jax.tree.map(
-                lambda t, rows: _ef_gather_next_contrib(
-                    t, cid, cid_next, rows, shard, impl=impl),
-                ef_all, aux["new_ef"]),
-            "total": _size_total(n_next),
-        }, shard)
+        summed = round_psum(contribs, ef_all, aux["new_ef"], cid, cid_next,
+                            n_next)
         state, metrics, ctrl = finish_fn(state, summed["round"], ctrl)
-        ef_all = jax.tree.map(
-            lambda t, full: _ef_scatter_local(t, cid, full, shard,
-                                              impl=impl),
-            ef_all, summed["scat"])
-        ef_next = _slice_positional(summed["gath"], shard, n.shape[0])
+        ef_all, ef_next = land_rows(ef_all, summed, cid, n.shape[0])
         if eval_fn is not None:
             metrics = {**metrics, **eval_fn(state, test[0], test[1])}
         return (state, ef_all, aux["bcast"], ef_next, summed["total"],
@@ -757,14 +766,16 @@ def _make_fused_compressed_superstep(bundle, fl, mode, n_rounds, uplink,
 
     def _prologue(ef_all, cids, sizes):
         # round 0's EF rows + weight total in one psum
-        seed = fused_psum({
-            "gather": jax.tree.map(
+        with part(CODEC):
+            gather = jax.tree.map(
                 lambda t: _ef_gather_contrib(t, cids[0], shard, impl=impl),
-                ef_all),
-            "total": _size_total(sizes[0]),
-        }, shard)
-        return _slice_positional(seed["gather"], shard,
-                                 sizes.shape[1]), seed["total"]
+                ef_all)
+        with part(AGGREGATE):
+            seed = fused_psum({"gather": gather,
+                               "total": _size_total(sizes[0])}, shard)
+        with part(CODEC):
+            rows = _slice_positional(seed["gather"], shard, sizes.shape[1])
+        return rows, seed["total"]
 
     if controller is not None:
         if participation:

@@ -160,8 +160,17 @@ def main() -> None:
                     help="engine only: stream host span traces / events to "
                          "this JSONL file (repro.obs.RunLog)")
     ap.add_argument("--profile", default=None, metavar="DIR",
-                    help="engine only: write a jax.profiler trace for the "
-                         "whole run into DIR")
+                    help="engine only: write a jax.profiler trace of the "
+                         "whole run, compile included, into DIR (open it "
+                         "in TensorBoard or Perfetto).  Device ops carry "
+                         "the round's scopes (local_train, codec, "
+                         "aggregate, eval) in their name stacks; the host "
+                         "line holds one 'superstep' step per chunk and "
+                         "the engine's spans (fit.prologue, ef.init, "
+                         "prefetch.stage.sample/.put/.fence, "
+                         "chunk.dispatch, eval.dispatch, ef.page.*, "
+                         "checkpoint.save), as in any jax.profiler "
+                         "session")
     ap.add_argument("--participation", default="full_sync",
                     help="engine only: round participation policy "
                          "(full_sync | deadline | buffered_async | any "

@@ -12,7 +12,7 @@ append-only stream of schema'd records:
   spans interleave correctly with the dispatch thread's chunk spans;
 * ``event``   — a point-in-time marker (run start/end, non-finite metric
   warnings, checkpoint writes);
-* ``counter`` — a named numeric sample (queue waits, staging-pool hits).
+* ``counter`` — a named numeric sample (queue waits, EF page hits).
 
 Records are plain dicts serialized by :func:`json_safe` (numpy scalars
 and small arrays included), streamed to a JSONL file as they are emitted
@@ -25,8 +25,18 @@ instrumented code calls the same API unconditionally and a run without
 observability allocates nothing per call.  ``as_runlog`` resolves the
 user-facing knob (None | path | RunLog) to one of the two.
 
-This module sits at the bottom of the import graph: stdlib + numpy only,
-so ``repro.fl.comm`` and ``repro.engine`` can both use the serializer
+Spans on the profiler's clock: while a ``jax.profiler`` session records
+(``jax.profiler.start_trace``, ``jax.profiler.trace``, or a capture
+through ``jax.profiler.start_server``), every span — of a ``RunLog`` and
+of :data:`NULL_RUNLOG` alike — is also a host ``TraceAnnotation`` of the
+same name, carrying the span's ``id`` as metadata when a ``RunLog``
+records it, so the engine's spans sit in the trace beside the device ops
+they drove.  With no session recording, a span costs one
+``is_enabled()`` check; without JAX loaded, not even that.
+
+This module sits at the bottom of the import graph: stdlib + numpy only
+(JAX's profiler is looked up in ``sys.modules``, never imported), so
+``repro.fl.comm`` and ``repro.engine`` can both use the serializer
 without cycles.
 """
 from __future__ import annotations
@@ -34,6 +44,7 @@ from __future__ import annotations
 import io
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Union
@@ -67,10 +78,28 @@ def json_safe(v: Any) -> Any:
     return str(v)
 
 
-class _Span:
-    """Context manager recording one timed interval into its RunLog."""
+_ANNOTATION = None     # jax.profiler.TraceAnnotation, once JAX is loaded
 
-    __slots__ = ("_log", "name", "attrs", "_t0", "_id", "_parent")
+
+def _recording():
+    """``jax.profiler.TraceAnnotation`` while a profiler session records,
+    else None.  JAX is read from ``sys.modules``: a process that never
+    loaded it has no session."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is None:
+            return None
+        _ANNOTATION = profiler.TraceAnnotation
+    return _ANNOTATION if _ANNOTATION.is_enabled() else None
+
+
+class _Span:
+    """Context manager recording one timed interval into its RunLog (and,
+    while a profiler session records, into the trace)."""
+
+    __slots__ = ("_log", "name", "attrs", "_t0", "_id", "_parent", "_ann")
 
     def __init__(self, log: "RunLog", name: str, attrs: Dict):
         self._log = log
@@ -78,12 +107,19 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
-        self._t0 = time.monotonic()
         self._id, self._parent = self._log._push_span()
+        annotation = _recording()
+        self._ann = (annotation(self.name, id=self._id)
+                     if annotation is not None else None)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._log._pop_span()
         rec = {"kind": "span", "name": self.name, "id": self._id,
                "parent": self._parent,
@@ -224,14 +260,20 @@ class NullRunLog:
 
     ``span`` returns ONE shared context manager instance so the
     instrumented hot loop costs a method call and nothing else — pinned
-    by the zero-allocation smoke test in ``tests/test_obs.py``.
+    by the zero-allocation smoke test in ``tests/test_obs.py``.  While a
+    profiler session records, ``span`` returns a ``TraceAnnotation`` of
+    the span's name instead: the trace shows the engine's spans even
+    when nothing records them here.
     """
 
     enabled = False
     path = None
 
-    def span(self, *a, **k):
-        return _NULL_SPAN
+    def span(self, name, **attrs):
+        annotation = _recording()
+        if annotation is None:
+            return _NULL_SPAN
+        return annotation(name)
 
     def event(self, *a, **k):
         pass

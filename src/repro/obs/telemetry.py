@@ -45,6 +45,8 @@ from typing import Any, Dict, FrozenSet, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import AGGREGATE, in_part
+
 __all__ = ["ClientTapCtx", "RoundTapCtx", "TelemetryTap", "Telemetry",
            "register_tap", "registered_taps", "make_telemetry",
            "TELEMETRY_PREFIX"]
@@ -270,6 +272,7 @@ class Telemetry:
     taps: Tuple[TelemetryTap, ...]
     round_ctx: RoundTapCtx = field(default_factory=RoundTapCtx)
 
+    @in_part(AGGREGATE)
     def client_sums(self, ctx: ClientTapCtx) -> Dict[str, jnp.ndarray]:
         """Flat namespaced psum-pending sums for one client."""
         out: Dict[str, jnp.ndarray] = {}
@@ -278,6 +281,7 @@ class Telemetry:
                 out[f"{tap.name}.{k}"] = jnp.asarray(v, jnp.float32)
         return out
 
+    @in_part(AGGREGATE)
     def finish(self, summed: Dict[str, jnp.ndarray]) -> Dict[str, Any]:
         """Summed (psum-completed) tap values -> emitted ``tele/`` metrics."""
         out: Dict[str, Any] = {}

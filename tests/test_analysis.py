@@ -1,6 +1,7 @@
 """repro.analysis: pass registry, jaxpr substrate, invariant passes and
 seeded-violation mutation tests (the analyzer must CATCH planted bugs —
 a green run proves nothing if the passes are vacuous)."""
+import contextlib
 import subprocess
 import sys
 import textwrap
@@ -19,7 +20,7 @@ from repro.analysis import (AnalysisPass, Finding, SuperstepSpec,
 from repro.analysis import registry as _registry
 
 BUILTIN_PASSES = ("collective-bytes", "collectives", "donation", "dtype",
-                  "host-sync", "source-lint")
+                  "host-sync", "scopes", "source-lint")
 
 
 # ---------------------------------------------------------------------------
@@ -235,6 +236,88 @@ def test_mutation_fake_wire_model_caught():
 
 
 # ---------------------------------------------------------------------------
+# Round-part scopes (repro.obs.scopes)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("spec", default_matrix("full", sharded=False),
+                         ids=lambda s: s.point)
+def test_round_body_under_one_scope(spec):
+    """Every equation of the round body, backward included, lies under
+    exactly one of local_train / codec / aggregate / eval."""
+    findings = make_pass("scopes").run(lower_superstep(spec))
+    assert not findings, [str(f) for f in findings]
+
+
+def _eval_superstep_jaxprs(codec):
+    """The round body of a superstep with eval folded into the scan, and
+    the boundary evaluator (``jit_eval``) alone."""
+    from repro.analysis import analysis_bundle, fl_for
+    from repro.analysis.lower import INPUT_SHAPE
+    from repro.compress import make_codec
+    from repro.engine.evaljit import make_eval_fn
+    from repro.engine.superstep import (abstract_superstep_args,
+                                        make_compressed_superstep,
+                                        make_plain_superstep)
+    bundle = analysis_bundle()
+    fl = fl_for(SuperstepSpec(codec=codec))
+    eval_fn = make_eval_fn(bundle, fl)
+    test = ({"x": jax.ShapeDtypeStruct((16,) + INPUT_SHAPE, jnp.float32),
+             "y": jax.ShapeDtypeStruct((16,), jnp.int32)},
+            jax.ShapeDtypeStruct((16,), jnp.bool_))
+    up = down = None
+    if fl.compressed:
+        up, down = make_codec(fl.uplink_codec, topk_frac=fl.topk_frac), \
+            make_codec(fl.downlink_codec)
+        model = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+        up.bind(model)
+        down.bind(model)
+        fn = make_compressed_superstep(bundle, fl, "client_parallel", 3, up,
+                                       down, eval_fn=eval_fn)
+    else:
+        fn = make_plain_superstep(bundle, fl, "client_parallel", 3,
+                                  eval_fn=eval_fn)
+    args = abstract_superstep_args(bundle, fl, 3, cohort=4, uplink=up,
+                                   ef_rows=8, input_shape=INPUT_SHAPE)
+    step = jax.make_jaxpr(fn)(*args, *test)
+    boundary = jax.make_jaxpr(eval_fn)(args[0], *test)
+    return round_body(step), boundary
+
+
+@pytest.mark.parametrize("codec", ["plain", "topk"])
+def test_eval_under_eval_scope(codec):
+    """Eval folded into the scan and the boundary evaluator alike run
+    under ``eval``: the body stays under one part per equation, and the
+    evaluator's forward convolutions are ``eval``'s."""
+    from repro.analysis.jaxprs import leaf_eqns_with_name_stacks
+    from repro.analysis.passes import parts_of, scope_findings
+    body, boundary = _eval_superstep_jaxprs(codec)
+    assert scope_findings(body) == []
+    in_body = {frozenset(parts_of(st)) for st, e in
+               leaf_eqns_with_name_stacks(body)
+               if e.primitive.name == "conv_general_dilated"}
+    assert frozenset({"eval"}) in in_body
+    assert frozenset({"local_train"}) in in_body
+    stacks = [parts_of(st) for st, _ in
+              leaf_eqns_with_name_stacks(boundary)]
+    assert stacks and all(p == {"eval"} for p in stacks)
+
+
+def test_mutation_unscoped_local_training_caught(monkeypatch):
+    """A round whose local training lost its scope is caught: its
+    equations sit under no part and its convolutions in the wrong one."""
+    from repro.obs import scopes
+    real = jax.named_scope
+    monkeypatch.setattr(
+        scopes.jax, "named_scope",
+        lambda name: (contextlib.nullcontext() if name == scopes.LOCAL_TRAIN
+                      else real(name)))
+    findings = make_pass("scopes").run(
+        lower_superstep(SuperstepSpec(codec="topk")))
+    msgs = " ".join(f.message for f in findings)
+    assert "under none of" in msgs and "conv_general_dilated" in msgs
+
+
+# ---------------------------------------------------------------------------
 # Sharded: known-good + seeded violations under forced 2 devices
 # ---------------------------------------------------------------------------
 
@@ -249,9 +332,15 @@ _SHARDED_ANALYSIS_SCRIPT = textwrap.dedent("""
     # known good: every lowered pass is clean on the fused sharded point
     low = lower_superstep(spec)
     for name in ("collectives", "host-sync", "dtype", "donation",
-                 "collective-bytes"):
+                 "collective-bytes", "scopes"):
         fs = make_pass(name).run(low)
         assert not fs, (name, [str(f) for f in fs])
+
+    # every sharded point's round body lies under the round's scopes
+    from repro.analysis import default_matrix
+    for sp in default_matrix("full", sharded=True):
+        fs = make_pass("scopes").run(lower_superstep(sp))
+        assert not fs, (sp.point, [str(f) for f in fs])
 
     # seeded: an EXTRA psum smuggled into the superstep body
     def add_psum(fn):

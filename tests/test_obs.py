@@ -328,12 +328,16 @@ def test_runlog_thread_local_nesting():
 
 def test_null_runlog_zero_allocation():
     """The disabled path hands back ONE shared span instance — no per-call
-    allocation in the hot loop — and records nothing."""
+    allocation in the hot loop — and records nothing, at every span site
+    the engine has, while no profiler session records (JAX loaded)."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     assert as_runlog(None) is NULL_RUNLOG
     assert isinstance(as_runlog(NULL_RUNLOG), NullRunLog)
     s1 = NULL_RUNLOG.span("chunk.dispatch", r0=0, r1=8)
     s2 = NULL_RUNLOG.span("anything.else")
     assert s1 is s2                      # the shared _NULL_SPAN singleton
+    for name in _ENGINE_SPANS + ("prefetch.stage.fence",):
+        assert NULL_RUNLOG.span(name, r0=0, r1=8) is s1, name
     with s1:
         pass
     NULL_RUNLOG.event("e")
@@ -341,6 +345,155 @@ def test_null_runlog_zero_allocation():
     NULL_RUNLOG.warning("w")
     assert NULL_RUNLOG.records() == []
     assert not NULL_RUNLOG.enabled and NULL_RUNLOG.path is None
+
+
+# ---------------------------------------------------------------------------
+# engine spans in any jax.profiler trace
+# ---------------------------------------------------------------------------
+
+# every span a fit with paged EF and checkpoints emits on the CPU (the
+# staging fence, prefetch.stage.fence, runs on accelerators only)
+_ENGINE_SPANS = ("fit.prologue", "ef.init", "prefetch.stage",
+                 "prefetch.stage.sample", "prefetch.stage.put",
+                 "chunk.dispatch", "eval.dispatch", "checkpoint.save",
+                 "ef.page.gather", "ef.page.writeback")
+
+
+def _host_events(trace_dir):
+    """(name, start_s, dur_s, stats) of the trace's host events that the
+    engine emits: its spans and its ``superstep`` steps."""
+    import glob
+    pb = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                          recursive=True))[-1]
+    data = jax.profiler.ProfileData.from_file(pb)
+    ours = set(_ENGINE_SPANS) | {"prefetch.stage.fence", "superstep"}
+    return [(ev.name, ev.start_ns / 1e9, ev.duration_ns / 1e9,
+             {k: v for k, v in ev.stats})
+            for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events
+            if ev.name in ours]
+
+
+@pytest.fixture(scope="module")
+def traced_fits(tmp_path_factory):
+    """One short fit under ``jax.profiler.start_trace`` with no RunLog,
+    then one with a RunLog: (events, None) and (events, records)."""
+    out = {}
+    for name, runlog in (("null", None), ("log", RunLog())):
+        base = tmp_path_factory.mktemp(name)
+        jax.profiler.start_trace(str(base / "trace"))
+        try:
+            res = run_federated(_bundle(), _fl_for("topk"), _data(),
+                                rounds=4, seed=1, eval_every=2,
+                                superstep_rounds=2, ef_store="host",
+                                checkpoint_dir=str(base / "ckpt"),
+                                checkpoint_every=2, runlog=runlog)
+        finally:
+            jax.profiler.stop_trace()
+        out[name] = (_host_events(str(base / "trace")),
+                     runlog.records() if runlog is not None else None,
+                     res.stats)
+    return out
+
+
+def test_engine_spans_in_profiler_trace_without_runlog(traced_fits):
+    """With ``runlog=None`` and no ``profile_dir``, an operator's trace
+    holds every engine span and one ``superstep`` step per chunk."""
+    events, _, _ = traced_fits["null"]
+    names = {e[0] for e in events}
+    assert set(_ENGINE_SPANS) <= names, set(_ENGINE_SPANS) - names
+    steps = sorted(st["step_num"] for n, _, _, st in events
+                   if n == "superstep")
+    assert steps == [0, 1]
+
+
+def test_runlog_spans_match_trace_events_on_one_clock(traced_fits):
+    """With a RunLog, each span record is exactly one trace event (same
+    name, its ``id`` as metadata), the durations agree within 0.5 ms, and
+    one constant maps every record's ``t0`` onto its event's start within
+    0.5 ms."""
+    events, records, _ = traced_fits["log"]
+    spans = [r for r in records if r["kind"] == "span"]
+    assert {r["name"] for r in spans} >= set(_ENGINE_SPANS)
+    by_id = {}
+    for name, start, dur, stats in events:
+        if name in _ENGINE_SPANS and "id" in stats:
+            by_id.setdefault(int(stats["id"]), []).append(
+                (name, start, dur))
+    assert len(by_id) == len(spans)
+    offsets = []
+    for r in spans:
+        match = by_id.get(r["id"], [])
+        assert [m[0] for m in match] == [r["name"]], (r, match)
+        _, start, dur = match[0]
+        assert abs(dur - r["dur"]) < 5e-4, (r, dur)
+        offsets.append(start - r["t0"])
+    mid = (max(offsets) + min(offsets)) / 2
+    assert all(abs(o - mid) < 5e-4 for o in offsets), offsets
+
+
+def test_staging_parts_tile_prefetch_stage(traced_fits):
+    """``prefetch.stage.sample`` / ``.put`` (/ ``.fence``) are the
+    children of each ``prefetch.stage`` and add up to it within 5%; the
+    fit's ``stage_*_s`` totals are their sums."""
+    _, records, stats = traced_fits["log"]
+    spans = [r for r in records if r["kind"] == "span"]
+    stages = [r for r in spans if r["name"] == "prefetch.stage"]
+    assert len(stages) == 2
+    kids_total = {"sample": 0.0, "put": 0.0, "fence": 0.0}
+    for st in stages:
+        kids = [r for r in spans if r["parent"] == st["id"]
+                and r["name"].startswith("prefetch.stage.")]
+        assert {k["name"] for k in kids} >= {"prefetch.stage.sample",
+                                             "prefetch.stage.put"}
+        assert sum(k["dur"] for k in kids) == pytest.approx(st["dur"],
+                                                            rel=0.05)
+        for k in kids:
+            kids_total[k["name"].rsplit(".", 1)[1]] += k["dur"]
+    for part_name, total in kids_total.items():
+        assert stats[f"stage_{part_name}_s"] == pytest.approx(
+            total, abs=2e-3), part_name
+
+
+def test_fit_prologue_and_start(traced_fits):
+    """``fit.prologue`` runs from the fit's entry to its loop's first
+    chunk, with the EF table's ``ef.init`` inside it, and
+    ``fit_start_s`` reaches the end of the first ``chunk.dispatch``."""
+    _, records, stats = traced_fits["log"]
+    spans = [r for r in records if r["kind"] == "span"]
+    pro = next(r for r in spans if r["name"] == "fit.prologue")
+    init = next(r for r in spans if r["name"] == "ef.init")
+    first = min((r for r in spans if r["name"] == "chunk.dispatch"),
+                key=lambda r: r["t0"])
+    assert pro["parent"] is None and init["parent"] == pro["id"]
+    assert pro["t0"] <= init["t0"] and \
+        init["t0"] + init["dur"] <= pro["t0"] + pro["dur"]
+    assert pro["t0"] + pro["dur"] <= first["t0"] + 1e-3
+    assert stats["fit_start_s"] == pytest.approx(
+        first["t0"] + first["dur"] - pro["t0"], abs=5e-3)
+
+
+def test_runlog_module_imports_no_jax():
+    """``repro.obs.runlog`` itself (loaded without its package) imports
+    stdlib and numpy only: it finds JAX's profiler in ``sys.modules``."""
+    import repro.obs.runlog as mod
+    code = textwrap.dedent(f"""
+        import importlib.util, sys
+        spec = importlib.util.spec_from_file_location(
+            "runlog", {mod.__file__!r})
+        m = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(m)
+        with m.NULL_RUNLOG.span("chunk.dispatch"):
+            pass
+        rl = m.RunLog()
+        with rl.span("chunk.dispatch"):
+            pass
+        assert "jax" not in sys.modules, "jax imported"
+        print("NO-JAX-OK")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert "NO-JAX-OK" in out.stdout, out.stderr
 
 
 def test_as_runlog_path(tmp_path):
