@@ -14,11 +14,10 @@ Codecs (select via ``FLConfig.uplink_codec`` / ``downlink_codec``):
     mask / lowrank      seed-expanded random sketching
 
 The quant hot paths (fused quantize+pack, scatter-unpack) run as Pallas
-kernels on TPU with pure-jnp references on CPU; a top-k threshold-select
-kernel is available via ``ops.topk_threshold_select`` for tie-free dense
-masking (the topk codec's residual uses the exact scatter complement so
-ties at the k-th magnitude never leak untransmitted mass) — see
-``repro.kernels.compress_pack`` and ``repro.kernels.ops``.
+kernels on TPU with pure-jnp references on CPU — see
+``repro.kernels.compress_pack`` and ``repro.kernels.ops``.  The round
+calls ``Codec.roundtrip``; the topk codec answers it with an exact,
+sort-free selection of the same k entries (``repro.compress.topk``).
 """
 from repro.compress.codec import Codec, IdentityCodec  # noqa: F401
 from repro.compress.quant import QuantCodec  # noqa: F401

@@ -14,6 +14,9 @@ Stateful codecs (error feedback) thread a per-client ``state`` pytree
 through ``encode``; the federated server persists one state per client
 across rounds (see ``repro.fl.server``).
 
+The round consumes ``roundtrip`` (encode, then decode, as the receiver
+sees it); ``encode`` alone defines the wire format and its bytes.
+
 Wire-format note: payload leaves are the transmitted buffers; seed-expanded
 codecs (sketching) additionally transmit one int32 seed per leaf, carried
 in the payload as an array so ``nbytes`` counts it.
@@ -57,6 +60,13 @@ class Codec:
         for d in self._shapes[i]:
             n *= d
         return n
+
+    def _leaves(self, tree):
+        leaves = jax.tree_util.tree_leaves(tree)
+        if len(leaves) != len(self._shapes):
+            raise ValueError(f"codec bound to a {len(self._shapes)}-leaf "
+                             f"tree, got {len(leaves)} leaves")
+        return leaves
 
     # -- per-leaf hooks -------------------------------------------------
     def _encode_leaf(self, x, state, key, i) -> Tuple[Any, Any]:
@@ -104,10 +114,7 @@ class Codec:
         ``level`` (a traced int32 scalar) selects the effective rung of a
         bound ladder (``set_ladder``); None encodes at the static
         configuration and traces exactly the pre-ladder program."""
-        leaves = jax.tree_util.tree_leaves(tree)
-        if len(leaves) != len(self._shapes):
-            raise ValueError(f"codec bound to a {len(self._shapes)}-leaf "
-                             f"tree, got {len(leaves)} leaves")
+        leaves = self._leaves(tree)
         if state is None:
             state = self.init_state()
         keys = (jax.random.split(key, len(leaves)) if key is not None
@@ -130,6 +137,15 @@ class Codec:
                   .astype(self._dtypes[i])
                   for i, p in enumerate(payload)]
         return jax.tree_util.tree_unflatten(self._treedef, leaves)
+
+    def roundtrip(self, tree, state=None, key=None, level=None):
+        """tree -> (decode(payload), new_state): what the receiver gets,
+        with the sender's new state.  The default is ``decode(encode(...))``
+        traced as such; a codec whose dense result is cheaper to build
+        without the payload overrides it, with the same values bit for
+        bit.  Wire bytes still come from ``encode``'s payload."""
+        payload, new_state = self.encode(tree, state, key, level=level)
+        return self.decode(payload), new_state
 
     def nbytes(self, payload) -> int:
         """True wire bytes of one payload (sum over transmitted buffers)."""

@@ -622,11 +622,11 @@ def _make_compressed_clients(bundle: ModelBundle, fl: FLConfig, mode: str,
             kd, ku = jax.random.split(key)
             down_update = jax.tree.map(lambda m, w: m - w,
                                        global_state["model"], down_mirror)
-            down_payload, _ = downlink.encode(
+            down_decoded, _ = downlink.roundtrip(
                 down_update, downlink.init_state(),   # stateless: see above
                 kd if downlink.uses_key else None)
             bcast = jax.tree.map(lambda w, d: w + d.astype(w.dtype),
-                                 down_mirror, downlink.decode(down_payload))
+                                 down_mirror, down_decoded)
             client_keys = _local_client_keys(ku, n_clients, shard)
             eff_bytes = (None if level is None or controller is None
                          else jnp.take(controller.spec.bytes_table(), level))
@@ -637,9 +637,8 @@ def _make_compressed_clients(bundle: ModelBundle, fl: FLConfig, mode: str,
             with part(CODEC):
                 delta = jax.tree.map(lambda a, b: a - b, trainable["model"],
                                      bcast)
-                payload, new_ef = uplink.encode(
+                decoded, new_ef = uplink.roundtrip(
                     delta, ef, ck if uplink.uses_key else None, level=level)
-                decoded = uplink.decode(payload)
                 if m is not None:
                     # dropped / late client: its payload never uplinked,
                     # so the residual it would have cleared stays local

@@ -12,6 +12,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU's library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -116,3 +118,26 @@ def test_kernel_gradient_compiles_for_v5e(one_chip, name):
                     argnums=tuple(range(n_diff)))
     text = jax.jit(grad).lower(*args).compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
+
+
+@pytest.mark.parametrize("ladder", [None, (0.01, 0.05)])
+def test_topk_roundtrip_compiles_without_sort_or_scatter(one_chip, ladder):
+    """The round's top-k uplink (10 clients vmapped over the FC weight, a
+    5% top-k with error feedback, static and on a traced ladder level)
+    compiles for the chip as counting passes and selects: no ``sort``
+    (what ``lax.top_k`` lowers to) and no ``scatter`` (a sparse decode or
+    residual) is left in the optimized program."""
+    from repro.compress import TopKCodec
+    c = TopKCodec(0.05).bind([jax.ShapeDtypeStruct((N_FC,), jnp.float32)])
+    if ladder is not None:
+        c.set_ladder(ladder)
+    level = None if ladder is None else _spec((), jnp.int32, one_chip)
+    rows = _spec((COHORT, N_FC), jnp.float32, one_chip)
+
+    def step(x, ef, lv):
+        return jax.vmap(lambda a, s: c.roundtrip([a], [s], level=lv))(x, ef)
+
+    text = jax.jit(step).lower(rows, rows, level).compile().as_text()
+    ops = re.findall(r"\s([a-z][\w-]*)\(", text)
+    assert ops, "no op found in the compiled program"
+    assert "sort" not in ops and "scatter" not in ops
